@@ -22,8 +22,8 @@ rng = np.random.default_rng(0)
 
 
 def timed(fn, x, iters=10):
-    # check_vma=False: required for the fused rows (0.4.x shard_map has no
-    # replication rule for pallas_call); harmless for the jnp rows.
+    # check_vma=False: the fused rows run pallas_call; harmless for the
+    # jnp rows.
     f = jax.jit(compat.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
                                  in_specs=(P("x"),), out_specs=P("x"),
                                  check_vma=False))

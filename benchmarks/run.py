@@ -49,304 +49,43 @@ import subprocess
 import sys
 import time
 
-import numpy as np
-
 
 def emit(name: str, us: float, derived: str = ""):
     print(f"{name},{us:.3f},{derived}")
 
 
 # ---------------------------------------------------------------------------
-def bench_rounds():
-    from repro.core import simulator as sim
-    from repro.core.schedule import ceil_log2
-
-    for p in [2, 3, 7, 8, 22, 31, 64, 100, 255, 256, 257, 1000]:
-        inputs = [[np.ones(1, np.float64) for _ in range(p)]
-                  for _ in range(p)]
-        t0 = time.perf_counter()
-        _, st = sim.simulate_reduce_scatter(inputs)
-        us = (time.perf_counter() - t0) * 1e6
-        st.assert_theorem1(p)
-        emit(f"rounds/reduce_scatter_p{p}", us,
-             f"rounds={st.rounds};blocks={st.blocks_sent[0]};"
-             f"theory_rounds={ceil_log2(p)};theory_blocks={p - 1}")
-    for p in [8, 22, 64, 257]:
-        inputs = [[np.ones(1, np.float64) for _ in range(p)]
-                  for _ in range(p)]
-        t0 = time.perf_counter()
-        _, st = sim.simulate_allreduce(inputs)
-        us = (time.perf_counter() - t0) * 1e6
-        st.assert_theorem2(p)
-        emit(f"rounds/allreduce_p{p}", us,
-             f"rounds={st.rounds};blocks={st.blocks_sent[0]};"
-             f"theory_rounds={2 * ceil_log2(p)};theory_blocks={2 * (p - 1)}")
+# Every group that touches JAX runs in a worker process of its own: this
+# process never imports JAX, so on a TPU host exactly one process at a
+# time holds the device.
+WORKERS = {
+    "rounds": ("_rounds_worker.py", ["rounds"], 900),
+    "cost_model": ("_rounds_worker.py", ["cost_model"], 900),
+    "collectives": ("_collective_worker.py", [], 900),
+    "kernels": ("_kernel_worker.py", [], 900),
+    "wire": ("_wire_worker.py", [], 900),
+    "plans": ("_plan_worker.py", [], 900),
+    "a2a": ("_a2a_worker.py", [], 900),
+    "overlap": ("_overlap_worker.py", [], 1200),
+    "elastic": ("_elastic_worker.py", [], 1800),
+    "serve": ("_serve_worker.py", [], 1800),
+}
 
 
-# ---------------------------------------------------------------------------
-def bench_cost_model():
-    from repro.core import cost_model as cm
-
-    model = cm.CommModel.tpu_v5e()
-    for p in [16, 64, 256, 1024]:
-        for m in [4096, 1 << 20, 1 << 28]:
-            rows = {
-                "circulant": cm.t_allreduce(m, p, model),
-                "circulant_torus": cm.t_allreduce(m, p, model, torus=True),
-                "ring": cm.t_ring_allreduce(m, p, model),
-                "reduce_bcast": cm.t_bcast_reduce_allreduce(m, p, model),
-            }
-            best = min(rows, key=rows.get)
-            for name, t in rows.items():
-                emit(f"cost_model/allreduce_p{p}_m{m}/{name}", t * 1e6,
-                     f"best={best}")
-        x = cm.crossover_m(p, model)
-        emit(f"cost_model/torus_crossover_p{p}", 0.0,
-             f"ring_beats_circulant_above_m={x:.3g}")
-    # Alltoall: hop-through-intermediate-ranks β volume (Bruck trade-off).
-    for p in [16, 64, 256]:
-        m = 1 << 20
-        entries = cm.a2a_round_entries(p)
-        emit(f"cost_model/alltoall_p{p}_m{m}", cm.t_alltoall(m, p, model) * 1e6,
-             f"rounds={len(entries)};blocks_sent={sum(entries)};"
-             f"volume_amplification={sum(entries) / (p - 1):.2f}x")
-
-
-# ---------------------------------------------------------------------------
-def bench_collectives():
+def run_worker(group: str) -> None:
+    """Run ``group``'s worker and pass its CSV rows through; a failed
+    worker becomes a ``<group>/ERROR`` row."""
+    script, args, timeout = WORKERS[group]
     here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_collective_worker.py")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=900, env=env)
+    proc = subprocess.run([sys.executable, os.path.join(here, script), *args],
+                          capture_output=True, text=True, timeout=timeout,
+                          env=env)
     if proc.returncode != 0:
-        emit("collectives/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
+        emit(f"{group}/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
         return
     print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_plans():
-    """Plan/execute API overhead gate: spec-driven dispatch must be
-    trace-free across repeated calls (frozen spec + lru-cached plan) and
-    must add zero collective-permutes over the schedule's round count —
-    the pre-redesign kwarg baseline.  Subprocess (needs fake devices)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_plan_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=900, env=env)
-    if proc.returncode != 0:
-        emit("plans/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_a2a():
-    """Alltoall(v) structural gate: round counts, ragged wire widths vs
-    the analytic bound, fused ratio, MoE ep parity.  Subprocess (needs
-    fake devices)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_a2a_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=900, env=env)
-    if proc.returncode != 0:
-        emit("a2a/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_overlap():
-    """Bucketed/overlapped grad-sync gate: pipelined round budgets,
-    bucketed-vs-unbucketed step ratio, trajectory equivalence.
-    Subprocess (needs fake devices)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_overlap_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=1200, env=env)
-    if proc.returncode != 0:
-        emit("overlap/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_elastic():
-    """Elastic fault-tolerance gate: shrink/grow drills resume within a
-    step boundary with verified re-plans and a reference-matching
-    post-resize trajectory.  Subprocess (needs fake devices)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_elastic_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=1800, env=env)
-    if proc.returncode != 0:
-        emit("elastic/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_serve():
-    """Serving gate: continuous-batching throughput + per-boundary p50/
-    p99 latency, bitwise scheduler-vs-one-shot parity, and the
-    ``kind="broadcast"`` weight-fan-out round counts (HLO collective-
-    permutes == ceil(log2 p)).  Subprocess (needs fake devices)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_serve_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=1800, env=env)
-    if proc.returncode != 0:
-        emit("serve/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_wire():
-    here = os.path.dirname(os.path.abspath(__file__))
-    worker = os.path.join(here, "_wire_worker.py")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, worker], capture_output=True,
-                          text=True, timeout=900, env=env)
-    if proc.returncode != 0:
-        emit("wire/ERROR", 0.0, proc.stderr[-200:].replace("\n", " "))
-        return
-    print(proc.stdout, end="")
-
-
-# ---------------------------------------------------------------------------
-def bench_kernels():
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import (fused_block_reduce, fused_round,
-                               quantize_blocks)
-    from repro.kernels import ref as R
-
-    rng = np.random.default_rng(0)
-    for shape in [(256, 512), (1024, 2048)]:
-        a = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        b = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        fused_block_reduce(a, b).block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(5):
-            out = fused_block_reduce(a, b)
-        out.block_until_ready()
-        us = (time.perf_counter() - t0) / 5 * 1e6
-        ref = R.block_reduce_ref(a, b)
-        ok = bool(jnp.allclose(out, ref))
-        emit(f"kernels/block_reduce_{shape[0]}x{shape[1]}", us,
-             f"allclose={ok};interpret=True")
-
-    # Fused circulant round (fold + next-send layout, one pass) vs the
-    # unfused jnp chain (reduce + concat + 2 slices) on one mid-game round
-    # shape: live 8 blocks, 4 received, keep/send split at 4.
-    def one_round(f):
-        @jax.jit
-        def run(live, T):
-            return f(live, T, nb=4, next_lo=4, op="add")
-        return run
-
-    fused_fn = one_round(fused_round)
-    unfused_fn = one_round(R.fused_round_ref)
-
-    def timed(f, live, T, iters=20):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            k, s = f(live, T)
-        k.block_until_ready()
-        s.block_until_ready()
-        return (time.perf_counter() - t0) / iters * 1e6
-
-    for cols in [16384, 65536]:
-        live = jnp.asarray(rng.standard_normal((8, cols)), jnp.float32)
-        T = jnp.asarray(rng.standard_normal((4, cols)), jnp.float32)
-        for f in (fused_fn, unfused_fn):  # warm up both before timing
-            k, s = f(live, T)
-            k.block_until_ready()
-        # Paired back-to-back reps: per-rep ratios cancel common-mode
-        # machine-load drift (shared CI runners swing several-x); the
-        # reported ratio is the median of the paired ratios.
-        t_fused, t_unfused, ratios = 1e30, 1e30, []
-        for _ in range(9):
-            tf = timed(fused_fn, live, T)
-            tu = timed(unfused_fn, live, T)
-            ratios.append(tf / tu)
-            t_fused, t_unfused = min(t_fused, tf), min(t_unfused, tu)
-        ratio = sorted(ratios)[len(ratios) // 2]
-        kf, sf = fused_fn(live, T)
-        ku, su = unfused_fn(live, T)
-        ok = bool(jnp.array_equal(kf, ku) and jnp.array_equal(sf, su))
-        emit(f"kernels/fused_round_8x{cols}", t_fused,
-             f"bitwise={ok};unfused_us={t_unfused:.3f};"
-             f"ratio={ratio:.3f};interpret=True")
-
-    x = jnp.asarray(rng.standard_normal((16, 4096)), jnp.float32)
-    t0 = time.perf_counter()
-    payload = quantize_blocks(x, group=512)
-    comp = payload["codes"].size + payload["scales"].size * 4
-    us = (time.perf_counter() - t0) * 1e6
-    emit("kernels/quantize_16x4096", us,
-         f"compression={x.size * 4 / comp:.2f}x")
-
-    # Compressed round (dequant + fold + requant-next-send, one pass) vs
-    # its jnp oracle on the same mid-game round geometry; both jitted —
-    # under jit the two are bitwise-equal (identical arithmetic; XLA
-    # makes the same contraction choices for both graphs).
-    from repro.kernels import fused_round_dq
-    from repro.kernels.ref import fused_round_dq_ref, quantize_ref
-
-    def one_dq_round(f):
-        @jax.jit
-        def run(live, c, s):
-            return f(live, c, s, nb=4, next_lo=4, op="add", group=512)
-        return run
-
-    dq_fused = one_dq_round(fused_round_dq)
-    dq_ref = one_dq_round(fused_round_dq_ref)
-    for cols in [16384, 65536]:
-        live = jnp.asarray(rng.standard_normal((8, cols)), jnp.float32)
-        c, s = quantize_ref(
-            jnp.asarray(rng.standard_normal((4, cols)), jnp.float32),
-            group=512)
-        c, s = jax.device_put(c), jax.device_put(s)
-
-        def timed_dq(f, iters=20):
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                k, sd = f(live, c, s)
-            k.block_until_ready()
-            return (time.perf_counter() - t0) / iters * 1e6
-
-        for f in (dq_fused, dq_ref):
-            k, _ = f(live, c, s)
-            k.block_until_ready()
-        t_fused, t_ref, ratios = 1e30, 1e30, []
-        for _ in range(9):
-            tf, tu = timed_dq(dq_fused), timed_dq(dq_ref)
-            ratios.append(tf / tu)
-            t_fused, t_ref = min(t_fused, tf), min(t_ref, tu)
-        ratio = sorted(ratios)[len(ratios) // 2]
-        kf, sf = dq_fused(live, c, s)
-        ku, su = dq_ref(live, c, s)
-        ok = bool(jnp.array_equal(kf, ku)
-                  and jnp.array_equal(sf[0], su[0])
-                  and jnp.array_equal(sf[1], su[1]))
-        emit(f"kernels/fused_round_dq_8x{cols}", t_fused,
-             f"bitwise={ok};unfused_us={t_ref:.3f};"
-             f"ratio={ratio:.3f};interpret=True")
 
 
 # ---------------------------------------------------------------------------
@@ -423,16 +162,7 @@ def bench_roofline():
 
 
 BENCHES = {
-    "rounds": bench_rounds,
-    "cost_model": bench_cost_model,
-    "collectives": bench_collectives,
-    "kernels": bench_kernels,
-    "wire": bench_wire,
-    "plans": bench_plans,
-    "a2a": bench_a2a,
-    "overlap": bench_overlap,
-    "elastic": bench_elastic,
-    "serve": bench_serve,
+    **{g: (lambda g=g: run_worker(g)) for g in WORKERS},
     "analysis": bench_analysis,
     "roofline": bench_roofline,
 }

@@ -1,29 +1,34 @@
-"""Version-adaptive JAX compatibility layer — the single import point for
-every JAX API whose surface moved between the 0.4.x line and the current
-(0.8.x) line.  Policy (see README §Supported JAX versions): repro code
-NEVER calls `jax.shard_map` / `jax.set_mesh` / `Compiled.cost_analysis()`
-directly; it calls the shims below, which present the NEW-style surface
-and adapt down to whatever the installed JAX provides.  When an API moves
-again, this module is the only file that changes (tests/test_compat.py
-smoke-checks every shim under the installed JAX so drift fails loudly in
-one place).
+"""The single import point for the JAX APIs whose surface has moved between
+releases.  The repository targets one JAX line (pinned in
+``requirements.txt``; see README §Supported JAX version).  Repro code
+NEVER calls `jax.shard_map` / `jax.set_mesh` / `jax.make_mesh` /
+`lax.axis_size` directly; it calls the shims below, so that when an API
+moves again this module is the only file that changes
+(tests/test_compat.py smoke-checks every shim under the installed JAX).
 
 Shims:
 
-  shard_map(...)       new-style signature (`axis_names=`, `check_vma=`);
-                       falls back to `jax.experimental.shard_map.shard_map`
-                       with `auto=` / `check_rep=` on 0.4.x.
-  use_mesh(mesh)       context manager activating `mesh`: `jax.set_mesh`
-                       where it exists, else the legacy `with mesh:` entry
-                       (which is what makes bare-PartitionSpec
-                       `with_sharding_constraint` calls resolvable on
-                       0.4.x).
-  cost_analysis(c)     always a flat `dict` (0.4.x returns a one-element
-                       list of dicts; newer JAX returns the dict itself).
-  ppermute(x, ...)     pytree-aware `lax.ppermute` (single call point for
+  shard_map(...)       ``jax.shard_map`` (``axis_names=`` = the MANUAL axes,
+                       ``check_vma=``).
+  make_mesh(...)       a mesh with every axis ``Auto`` (``jax.make_mesh``, or
+                       an explicit device subset as given): bare-spec
+                       ``with_sharding_constraint`` calls and partial-manual
+                       ``shard_map`` regions are written against Auto axes
+                       (JAX's default is ``Explicit``, under which a
+                       bare-spec constraint becomes an assertion).
+  use_mesh(mesh)       ``jax.set_mesh`` context (resolves bare
+                       PartitionSpecs against ``mesh``).
+  cost_analysis(c)     ``Compiled.cost_analysis()`` as a flat dict ({} when
+                       the backend gives none).
+  axis_size(name)      static size of a mapped axis at trace time.
+  ppermute(x, ...)     pytree-aware ``lax.ppermute`` (single call point for
                        the circulant collectives' per-round sends).
-  make_mesh(...)       `jax.make_mesh` where present, manual `Mesh`
-                       construction otherwise.
+  tpu_topology(name)   a described TPU topology (e.g. ``"v5e:2x2"``): its
+                       ``devices`` let the TPU compiler compile for a chip
+                       that is not attached.
+  persistent_cache_off()  context with JAX's persistent compilation cache
+                       disabled (a compile for a described chip cannot be
+                       read back from it).
 """
 from __future__ import annotations
 
@@ -33,149 +38,83 @@ from typing import Any, Callable, Sequence
 import jax
 import numpy as np
 from jax import lax
+from jax.sharding import AxisType, Mesh
 
-
-def _parse_version(v: str) -> tuple[int, ...]:
-    parts = []
-    for tok in v.split(".")[:3]:
-        digits = "".join(ch for ch in tok if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple[int, ...] = _parse_version(jax.__version__)
-
-HAS_NATIVE_SHARD_MAP: bool = hasattr(jax, "shard_map")
-HAS_SET_MESH: bool = hasattr(jax, "set_mesh")
-HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
-
-# 0.4.x accepts partial-manual regions (legacy ``auto=``), but the XLA it
-# bundles cannot SPMD-partition collective-permute / all-gather instructions
-# created inside a manual subgroup (hard CHECK crash in spmd_partitioner.cc).
-# Callers that mix manual-axis ppermute collectives with auto (GSPMD) axes
-# must fall back to a fully-manual region when this is False.
-SUPPORTS_PARTIAL_MANUAL_COLLECTIVES: bool = HAS_NATIVE_SHARD_MAP
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               axis_names: set | frozenset | None = None,
               check_vma: bool | None = None) -> Callable:
-    """New-style ``jax.shard_map`` signature on every supported JAX.
-
-    ``axis_names`` is the set of MANUAL mesh axes (None = all axes manual,
-    the common full-manual case).  On 0.4.x this maps to the legacy
-    ``auto=`` complement; ``check_vma`` maps to ``check_rep``.  Partial-
-    manual regions force replication checking off on 0.4.x (the legacy
-    checker does not support auto axes).
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        kw: dict[str, Any] = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    kw = {}
+    """``jax.shard_map``; ``axis_names`` is the set of MANUAL mesh axes
+    (None = all axes manual, the common full-manual case)."""
+    kw: dict[str, Any] = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-            kw["check_rep"] = False  # legacy checker can't handle auto axes
+        kw["axis_names"] = set(axis_names)
     if check_vma is not None:
-        kw["check_rep"] = kw.get("check_rep", True) and check_vma
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
-
-# ---------------------------------------------------------------------------
-# Mesh construction / activation
-# ---------------------------------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` where available, manual Mesh assembly otherwise."""
+    """A mesh with ``Auto`` axis types on every axis: ``jax.make_mesh``
+    over the runtime's devices, or ``devices`` laid out in the order
+    given (``jax.make_mesh`` insists on a whole physical TPU mesh, and an
+    explicit subset -- p < device count, an elastic run's survivors -- is
+    not one)."""
     axis_shapes = tuple(axis_shapes)
     axis_names = tuple(axis_names)
-    if HAS_MAKE_MESH:
-        if devices is not None:
-            return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-        return jax.make_mesh(axis_shapes, axis_names)
-    from jax.sharding import Mesh
-    devs = list(devices) if devices is not None else jax.devices()
-    n = int(np.prod(axis_shapes))
-    if len(devs) < n:
-        raise ValueError(f"mesh {axis_shapes} needs {n} devices, "
-                         f"have {len(devs)}")
-    return Mesh(np.asarray(devs[:n]).reshape(axis_shapes), axis_names)
+    types = (AxisType.Auto,) * len(axis_names)
+    if devices is None:
+        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
+    return Mesh(np.asarray(list(devices)).reshape(axis_shapes), axis_names,
+                axis_types=types)
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Activate ``mesh`` for the enclosed region.
+    """Activate ``mesh`` for the enclosed region (``jax.set_mesh``)."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
-    New JAX: ``jax.set_mesh`` (required for explicit-sharding jnp ops and
-    bare-spec constraints).  0.4.x: the legacy ``with mesh:`` context,
-    which is what lets ``with_sharding_constraint(x, P(...))`` with a bare
-    PartitionSpec resolve axis names.
-    """
-    if HAS_SET_MESH:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-# ---------------------------------------------------------------------------
-# Compiled-artifact introspection
-# ---------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: always a flat dict.
-
-    JAX <= 0.4.x returns a list with one dict per program (a jitted
-    function has exactly one); newer JAX returns the dict directly.
-    Returns {} when the backend provides no analysis.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    if isinstance(ca, (list, tuple)):
-        for entry in ca:
-            if isinstance(entry, dict):
-                return dict(entry)
-        return {}
-    raise TypeError(f"unrecognized cost_analysis() return: {type(ca)!r}")
-
-
-# ---------------------------------------------------------------------------
-# Collective primitives
-# ---------------------------------------------------------------------------
-
-HAS_LAX_AXIS_SIZE: bool = hasattr(lax, "axis_size")
+    """``Compiled.cost_analysis()`` as a flat dict ({} when absent)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis at trace time.
-
-    ``lax.axis_size`` where it exists; on 0.4.x ``lax.psum(1, axis)``
-    constant-folds to the Python int the schedule computation needs.
-    """
-    if HAS_LAX_AXIS_SIZE:
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a mapped axis at trace time."""
+    return lax.axis_size(axis_name)
 
 
 def ppermute(x, axis_name: str, perm: Sequence[tuple[int, int]]):
     """Pytree-aware ``lax.ppermute`` (safe for compressed payload trees)."""
     return jax.tree.map(
         lambda leaf: lax.ppermute(leaf, axis_name, perm), x)
+
+
+# ---------------------------------------------------------------------------
+# Compiling for a TPU that is not attached
+# ---------------------------------------------------------------------------
+
+def tpu_topology(name: str):
+    """``jax.experimental.topologies`` description of TPU topology
+    ``name``; loads the TPU library, so call it only where one process at
+    a time does."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu", topology_name=name)
+
+
+@contextlib.contextmanager
+def persistent_cache_off():
+    """Disable JAX's persistent compilation cache for the enclosed
+    region, restoring the previous setting after."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)

@@ -150,9 +150,8 @@ def sweep_cases(p: int) -> list[Case]:
 def _shmap1(mesh, fn, check_vma: bool | None = None):
     """Per-rank fn over a (p, ...) global sharded on axis 0 (the repo's
     standard v[0]-unwrap convention).  ``check_vma=False`` is passed only
-    for the fused cases — 0.4.x shard_map has no replication rule for
-    pallas_call — so the jnp/baseline cases keep exercising the
-    replication checker."""
+    for the fused (pallas_call) cases, so the jnp/baseline cases keep
+    exercising the replication checker."""
     return jax.jit(compat.shard_map(
         lambda v: fn(v[0])[None], mesh=mesh,
         in_specs=(P(AXIS),), out_specs=P(AXIS), check_vma=check_vma))

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro import compat
 from . import quantize as _qz
 from .block_reduce import DEFAULT_COL_TILE, _OPS
 
@@ -53,18 +52,11 @@ def resolve_fused(use_fused_kernel: bool | None) -> bool:
     """Auto-selection rule for the ``use_fused_kernel`` kwarg.
 
     ``True``/``False`` are explicit.  ``None`` (auto) enables the fused
-    Pallas path only on TPU with a native post-0.4.x shard_map
-    (``compat.HAS_NATIVE_SHARD_MAP``): on CPU the kernel would run in
-    interpret mode, which is for
-    validation rather than speed, and the legacy 0.4.x shard_map has no
-    replication rule for pallas_call — auto must not change the default
-    behavior of call sites that keep replication checking on, so there
-    the jnp fallback is preserved (opt in with ``use_fused_kernel=True``
-    plus ``check_vma=False``).
+    Pallas path only on TPU: on CPU the kernel would run in interpret
+    mode, which is for validation rather than speed.
     """
     if use_fused_kernel is None:
-        return (jax.default_backend() == "tpu"
-                and compat.HAS_NATIVE_SHARD_MAP)
+        return jax.default_backend() == "tpu"
     return bool(use_fused_kernel)
 
 
@@ -183,34 +175,37 @@ def _dq_round_body(x_ref, c_ref, s_ref, keep_ref, send_c_ref, send_s_ref, *,
     received payload arrives as int8 codes + f32 scales (dequantized in
     VMEM, never materialized as f32 in HBM) and the next round's send rows
     leave requantized.  Elementwise expressions mirror ``ref.quantize_ref``
-    / ``ref.dequant_ref`` exactly so the interpret path is bitwise-equal
-    to the jnp reference path."""
+    / ``ref.dequant_ref`` exactly so the kernel is bitwise-equal to the
+    jnp reference path.  Every row range is read from a ref slice and
+    quantized on its own (quantization is per row): Mosaic rejects the
+    layout of a row slice taken from a loaded multi-row value."""
     reduce_fn = _OPS[op]
     cols = c_ref.shape[1]
-    q = c_ref[...].astype(jnp.float32).reshape(nb, cols // g, g)
-    deq = (q * s_ref[...][..., None]).reshape(nb, cols)
-    folded = reduce_fn(x_ref[:nb], deq)
+
+    def fold(r0, r1):
+        q = c_ref[r0:r1].astype(jnp.float32).reshape(r1 - r0, cols // g, g)
+        deq = (q * s_ref[r0:r1][..., None]).reshape(r1 - r0, cols)
+        return reduce_fn(x_ref[r0:r1], deq)
+
+    def send_rows(r0, r1, val):
+        sg, scale = _qz.group_scales(val, g)
+        codes = jnp.clip(jnp.round(sg / scale[..., None]), -127, 127)
+        _store_rows(send_c_ref, r0, r1,
+                    codes.reshape(r1 - r0, cols).astype(jnp.int8))
+        _store_rows(send_s_ref, r0, r1, scale)
+
     a = min(nb, next_lo)
     if a:
-        _store_rows(keep_ref, 0, a, folded[:a] if a < nb else folded)
+        _store_rows(keep_ref, 0, a, fold(0, a))
     if a < next_lo:
         _store_rows(keep_ref, a, next_lo, x_ref[a:next_lo])
     if send_c_ref is None:
         return
-    parts = []
     if nb > next_lo:
-        parts.append(folded[next_lo:nb])
+        send_rows(0, nb - next_lo, fold(next_lo, nb))
     b = max(nb, next_lo)
     if b < lo:
-        parts.append(x_ref[b:lo])
-    send = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-    ns = lo - next_lo
-    sg = send.reshape(ns, cols // g, g)
-    amax = jnp.max(jnp.abs(sg), axis=2)
-    scale = amax * _qz._INV127 + _qz._EPS
-    codes = jnp.clip(jnp.round(sg / scale[..., None]), -127, 127)
-    send_c_ref[...] = codes.reshape(ns, cols).astype(jnp.int8)
-    send_s_ref[...] = scale
+        send_rows(b - next_lo, lo - next_lo, x_ref[b:lo])
 
 
 def _dq_kernel_keep_send(x_ref, c_ref, s_ref, keep_ref, send_c_ref,
@@ -234,7 +229,6 @@ def fused_round_dq(
     next_lo: int,
     op: str = "add",
     group: int = _qz.DEFAULT_GROUP,
-    col_tile: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     """One fused COMPRESSED circulant round over 2-D buffers.
@@ -276,21 +270,20 @@ def fused_round_dq(
                      jax.ShapeDtypeStruct((ns, ng), jnp.float32)]
     kw: dict = {"interpret": True}
     if not interpret:
-        # Compiled (TPU): column tiles aligned to whole quantization
-        # groups so each grid step owns its scales slice.
-        ct = DEFAULT_COL_TILE if col_tile is None else col_tile
-        ct = min(cols, max(g, (ct // g) * g))
+        # Compiled (TPU): column tiles of whole quantization groups, so
+        # each grid step owns its scales slice (``quantize.tile_cols``).
+        ct, sct = _qz.tile_cols(cols, g)
         out_specs: object = pl.BlockSpec((next_lo, ct), lambda j: (0, j))
         if not final:
             out_specs = [out_specs,
                          pl.BlockSpec((ns, ct), lambda j: (0, j)),
-                         pl.BlockSpec((ns, ct // g), lambda j: (0, j))]
+                         pl.BlockSpec((ns, sct), lambda j: (0, j))]
         kw = {
             "grid": (pl.cdiv(cols, ct),),
             "in_specs": [
                 pl.BlockSpec((lo, ct), lambda j: (0, j)),
                 pl.BlockSpec((nb, ct), lambda j: (0, j)),
-                pl.BlockSpec((nb, ct // g), lambda j: (0, j)),
+                pl.BlockSpec((nb, sct), lambda j: (0, j)),
             ],
             "out_specs": out_specs,
         }
@@ -308,7 +301,7 @@ def quantize_rows(x: jax.Array, *, group: int = _qz.DEFAULT_GROUP,
     round-0 send quantization of the compressed collectives."""
     if interpret is None:
         interpret = _interpret_default()
-    return _qz.quantize(x, group=group, row_tile=1, interpret=interpret)
+    return _qz.quantize(x, group=group, interpret=interpret)
 
 
 def _permute_kernel(x_ref, o_ref, *, perm: tuple[int, ...]):

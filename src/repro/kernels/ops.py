@@ -63,7 +63,7 @@ def quantize_blocks(x: jax.Array, *, group: int = _qz.DEFAULT_GROUP,
     if backend == "jnp":
         codes, scales = _ref.quantize_ref(x2, group=g)
     else:
-        codes, scales = _qz.quantize(x2, group=g, row_tile=1,
+        codes, scales = _qz.quantize(x2, group=g,
                                      interpret=_interpret_default())
     return {"codes": codes, "scales": scales,
             "meta": (orig_shape, cols, g)}
@@ -86,8 +86,7 @@ def dequant_accumulate(acc: jax.Array, payload, *,
                                    group=g)
     else:
         out = _qz.dequant_add(acc2, payload["codes"], payload["scales"],
-                              group=g, row_tile=1,
-                              interpret=_interpret_default())
+                              group=g, interpret=_interpret_default())
     return out.reshape(orig_shape)
 
 

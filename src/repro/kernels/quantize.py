@@ -2,13 +2,14 @@
 communication rounds (β-term reducer, DESIGN §3).
 
 ``quantize``    : f32/bf16 (rows, cols) → int8 codes + f32 scales, one
-                  scale per (row_tile=1, col_tile) group.
+                  scale per (row, group of G columns).
 ``dequant_add`` : fused decompress-and-reduce — acc + codes * scale in one
                   VMEM pass (the receive side of a compressed round; fuses
                   the paper's ⊕ with decompression so the int8 payload is
                   never materialized as f32 in HBM).
 
-Group layout: scales[i, g] covers codes[i, g*G:(g+1)*G].  G = col_tile.
+Group layout: scales[i, g] covers codes[i, g*G:(g+1)*G].  Each grid step
+owns whole groups and their scales (``tile_cols``).
 Ragged shapes (rows not divisible by ``row_tile``, cols not divisible by
 ``group``) are zero-padded internally and sliced back — the last group of
 a row may cover fewer than G real elements; its scale is the amax of the
@@ -26,6 +27,8 @@ supported JAX lowers on every backend.
 Target: TPU; validated on CPU via interpret=True.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -71,12 +74,41 @@ def pad2d(x: jax.Array, row_mult: int, col_mult: int) -> jax.Array:
 _pad2 = pad2d  # internal alias used by the kernels below
 
 
-def _quantize_kernel(x_ref, codes_ref, scale_ref):
-    x = x_ref[...].astype(jnp.float32)          # (rt, G)
-    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # (rt, 1)
-    scale = amax * _INV127 + _EPS
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    codes_ref[...] = q
+_SCALE_LANES = 128
+
+
+def tile_cols(cols: int, g: int) -> tuple[int, int]:
+    """Column tile of the int8 kernels for a ``cols``-wide buffer whose
+    ``cols`` is a whole number of ``g``-wide groups: ``(ct, sct)``, the
+    payload columns and scale columns each grid step owns.
+
+    The TPU tiling takes a block's last dimension only when it is a
+    multiple of 128 or the array's whole width.  A scale block therefore
+    spans either every group of the row (up to 128 groups: one tile) or
+    exactly 128 groups (``ct = 128 * g`` payload columns per step; the
+    last step may be partial, and its out-of-range groups are dropped on
+    write)."""
+    ng = cols // g
+    if ng <= _SCALE_LANES:
+        return cols, ng
+    return _SCALE_LANES * g, _SCALE_LANES
+
+
+def group_scales(x: jax.Array, g: int) -> tuple[jax.Array, jax.Array]:
+    """(rows, k*g) f32 -> (grouped (rows, k, g), scales (rows, k)): the
+    per-group amax scale, one expression shared by the kernels and the
+    jnp oracle (``ref.quantize_ref``) so both round identically."""
+    rows, cols = x.shape
+    xg = x.reshape(rows, cols // g, g)
+    amax = jnp.max(jnp.abs(xg), axis=2)
+    return xg, amax * _INV127 + _EPS
+
+
+def _quantize_kernel(x_ref, codes_ref, scale_ref, *, g: int):
+    rows, ct = x_ref.shape
+    xg, scale = group_scales(x_ref[...].astype(jnp.float32), g)
+    q = jnp.clip(jnp.round(xg / scale[..., None]), -127, 127)
+    codes_ref[...] = q.reshape(rows, ct).astype(jnp.int8)
     scale_ref[...] = scale
 
 
@@ -100,14 +132,14 @@ def quantize(
     rt = min(row_tile, rows)
     xp = _pad2(x, rt, g)
     rp, cp = xp.shape
-    grid = (rp // rt, cp // g)
+    ct, sct = tile_cols(cp, g)
     codes, scales = pl.pallas_call(
-        _quantize_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rt, g), lambda i, j: (i, j))],
+        functools.partial(_quantize_kernel, g=g),
+        grid=(rp // rt, pl.cdiv(cp, ct)),
+        in_specs=[pl.BlockSpec((rt, ct), lambda i, j: (i, j))],
         out_specs=[
-            pl.BlockSpec((rt, g), lambda i, j: (i, j)),
-            pl.BlockSpec((rt, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((rt, ct), lambda i, j: (i, j)),
+            pl.BlockSpec((rt, sct), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rp, cp), jnp.int8),
@@ -121,11 +153,12 @@ def quantize(
     return codes, scales
 
 
-def _dequant_add_kernel(acc_ref, codes_ref, scale_ref, o_ref):
+def _dequant_add_kernel(acc_ref, codes_ref, scale_ref, o_ref, *, g: int):
+    rows, ct = codes_ref.shape
     acc = acc_ref[...].astype(jnp.float32)
-    q = codes_ref[...].astype(jnp.float32)
-    s = scale_ref[...]                            # (rt, 1) broadcast
-    o_ref[...] = (acc + q * s).astype(o_ref.dtype)
+    q = codes_ref[...].astype(jnp.float32).reshape(rows, ct // g, g)
+    deq = (q * scale_ref[...][..., None]).reshape(rows, ct)
+    o_ref[...] = (acc + deq).astype(o_ref.dtype)
 
 
 def dequant_add(
@@ -155,16 +188,14 @@ def dequant_add(
     rp, cp = codesp.shape
     scalesp = scales if rp == rows else jnp.pad(scales, ((0, rp - rows),
                                                          (0, 0)))
-    grid = (rp // rt, cp // g)
+    ct, sct = tile_cols(cp, g)
+    tile = pl.BlockSpec((rt, ct), lambda i, j: (i, j))
     out = pl.pallas_call(
-        _dequant_add_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rt, g), lambda i, j: (i, j)),
-            pl.BlockSpec((rt, g), lambda i, j: (i, j)),
-            pl.BlockSpec((rt, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((rt, g), lambda i, j: (i, j)),
+        functools.partial(_dequant_add_kernel, g=g),
+        grid=(rp // rt, pl.cdiv(cp, ct)),
+        in_specs=[tile, tile,
+                  pl.BlockSpec((rt, sct), lambda i, j: (i, j))],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rp, cp), acc.dtype),
         interpret=interpret,
     )(accp, codesp, scalesp)

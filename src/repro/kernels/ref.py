@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 # Shared with the kernels — the bitwise kernel-vs-oracle contract depends
 # on both sides using the exact same constants and op shapes.
-from .quantize import _EPS, _INV127
+from .quantize import group_scales
 
 
 def block_reduce_ref(a: jax.Array, b: jax.Array, *, op: str = "add") -> jax.Array:
@@ -46,9 +46,7 @@ def quantize_ref(x: jax.Array, *, group: int = 512
     rows, cols = x.shape
     g = min(group, cols)
     xp = _pad_cols(x.astype(jnp.float32), g)
-    xg = xp.reshape(rows, -1, g)
-    amax = jnp.max(jnp.abs(xg), axis=2)                    # (rows, ng)
-    scale = amax * _INV127 + _EPS
+    xg, scale = group_scales(xp, g)                        # scale (rows, ng)
     q = jnp.clip(jnp.round(xg / scale[..., None]), -127, 127)
     codes = q.reshape(rows, xp.shape[1]).astype(jnp.int8)
     return codes[:, :cols], scale

@@ -78,12 +78,20 @@ class _null_ctx:
 
 
 def resolve_cfg(arch: str, *, scale_down: bool = False,
-                moe_dispatch: str | None = None):
-    """Arch-name → config, with the scale-down and MoE-dispatch knobs
-    every entry point exposes resolved identically."""
+                moe_dispatch: str | None = None,
+                n_layers: int | None = None):
+    """Arch-name → config, with the scale-down, MoE-dispatch and depth
+    knobs every entry point exposes resolved identically.  ``n_layers``
+    cuts depth only (every width stays as published): how a model that
+    does not fit whole is sized for one chip."""
     cfg = get_config(arch)
     if scale_down:
         cfg = cfg.scaled_down()
+    if n_layers is not None:
+        import dataclasses as _dc
+        if not 1 <= n_layers <= cfg.n_layers:
+            raise ValueError(f"n_layers={n_layers} outside 1..{cfg.n_layers}")
+        cfg = _dc.replace(cfg, n_layers=n_layers)
     if moe_dispatch is not None:
         if not cfg.is_moe:
             raise ValueError(
@@ -111,6 +119,7 @@ def build_session(*, arch: str, scale_down: bool = False, steps: int = 100,
                   use_fused_kernel: bool | None = None,
                   bucket_bytes: int | None = None,
                   moe_dispatch: str | None = None,
+                  n_layers: int | None = None,
                   lr: float = 3e-4, warmup: int = 20,
                   compress: str | None = None,
                   devices=None, seed: int = 0,
@@ -124,7 +133,7 @@ def build_session(*, arch: str, scale_down: bool = False, steps: int = 100,
     restore them from a checkpoint anyway).
     """
     cfg = resolve_cfg(arch, scale_down=scale_down,
-                      moe_dispatch=moe_dispatch)
+                      moe_dispatch=moe_dispatch, n_layers=n_layers)
     mode = mode or ("single" if dp * mp == 1 else "zero1")
     opt_cfg = AdamWConfig(lr=lr, warmup_steps=warmup, total_steps=steps)
     pipe = for_model(cfg, seq_len=seq_len, global_batch=global_batch)
@@ -153,11 +162,15 @@ def build_session(*, arch: str, scale_down: bool = False, steps: int = 100,
                    opt_cfg=opt_cfg, sync=sync, built=built, pipe=pipe,
                    world=dp if mode != "single" else 1)
     if init_state:
-        sess.params = model.init(jax.random.PRNGKey(seed))
-        sess.opt = built.init_opt(sess.params)
-        if mode == "zero1":
-            sess.opt = jax.device_put(sess.opt,
-                                      built.opt_spec(sess.params))
+        # Params and optimizer state start where the step returns them,
+        # so step 1 runs step 0's compiled program.  Each is built by one
+        # jitted program rather than op by op.
+        key = jax.random.PRNGKey(seed)
+        shapes = jax.eval_shape(model.init, key)
+        sess.params = jax.jit(model.init, out_shardings=built.param_sharding(
+            shapes))(key)
+        sess.opt = jax.jit(built.init_opt, out_shardings=built.opt_spec(
+            shapes))(sess.params)
     return sess
 
 
@@ -212,7 +225,7 @@ def build_serve_session(*, arch: str, max_len: int,
     if replicas > 1:
         require_devices(replicas, f"{replicas} serving replicas")
     model = build(cfg, recipe=None, remat=False)
-    params = model.init(jax.random.PRNGKey(seed))
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
     rs = ReplicaSet(model, max_len, replicas, temperature=temperature,
                     schedule=broadcast_schedule, engine_mesh=ep_mesh)
     stats = rs.push_weights(params)
@@ -221,12 +234,12 @@ def build_serve_session(*, arch: str, max_len: int,
 
 
 def place_batch(sess: Session, batch: dict) -> dict:
-    batch = {k: jnp.asarray(v) for k, v in batch.items()}
-    if sess.mesh is not None:
-        batch = {k: jax.device_put(
-            v, NamedSharding(sess.mesh, sess.built.batch_spec))
+    if sess.mesh is None:
+        where = jax.devices()[0]
+    else:
+        where = NamedSharding(sess.mesh, sess.built.batch_spec)
+    return {k: jax.device_put(np.asarray(v), where)
             for k, v in batch.items()}
-    return batch
 
 
 def run_step(sess: Session, step: int) -> dict:
@@ -258,7 +271,7 @@ def restore_session(sess: Session, mgr, step: int | None = None
     ``resize_zero1_state`` to ``sess.world``, then place on the mesh.
     """
     s, params, opt_arrs, man = mgr.restore(step, sess.params)
-    sess.params = params
+    sess.params = jax.device_put(params, sess.built.param_sharding(params))
     n = sum(1 for k in opt_arrs if k.startswith("leaf_"))
     treedef = jax.tree.structure(sess.opt)
     if n != treedef.num_leaves:
@@ -272,10 +285,6 @@ def restore_session(sess: Session, mgr, step: int | None = None
         if saved_world != sess.world:
             state = resize_zero1_state(state, sess.params, sess.world,
                                        sess.sync)
-        state = jax.device_put(
-            jax.tree.map(jnp.asarray, state),
-            sess.built.opt_spec(sess.params))
-    else:
-        state = jax.tree.map(jnp.asarray, state)
-    sess.opt = state
+    sess.opt = jax.device_put(jax.tree.map(jnp.asarray, state),
+                              sess.built.opt_spec(sess.params))
     return s, man

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import ALIASES
 from repro.launch.bootstrap import build_serve_session
+from repro.launch.compile import setup_compile_cache
 
 
 def main(argv=None):
@@ -49,6 +50,7 @@ def main(argv=None):
                          "must divide prompt-len + max-new)")
     args = ap.parse_args(argv)
 
+    setup_compile_cache()
     try:
         sess = build_serve_session(
             arch=args.arch, max_len=args.prompt_len + args.max_new,

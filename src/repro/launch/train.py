@@ -18,6 +18,7 @@ from repro.checkpoint import CheckpointManager, config_fingerprint
 from repro.configs import ALIASES
 from repro.ft import FailureInjector, Watchdog
 from repro.launch import bootstrap
+from repro.launch.compile import CompileCounter, setup_compile_cache
 
 
 def main(argv=None):
@@ -72,6 +73,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     d, m = (int(x) for x in args.mesh.split("x"))
+    setup_compile_cache()
     try:
         sess = bootstrap.build_session(
             arch=args.arch, scale_down=args.scale_down, steps=args.steps,
@@ -100,11 +102,13 @@ def main(argv=None):
     injector = FailureInjector(fail_at_step=args.fail_at_step)
     wd = Watchdog()
     losses = []
+    first, later = CompileCounter(), CompileCounter()
     with sess.use_mesh():
         for step in range(start, args.steps):
             injector.check(step)
             t0 = time.time()
-            metrics = bootstrap.run_step(sess, step)
+            with first if step == start else later:
+                metrics = bootstrap.run_step(sess, step)
             dt = time.time() - t0
             status = wd.observe(step, dt)
             losses.append(float(metrics["loss"]))
@@ -123,6 +127,7 @@ def main(argv=None):
     if mgr:
         mgr.wait()
     print(f"final loss: {losses[-1]:.4f} (start {losses[0]:.4f})")
+    print(f"compiles: first step {first.count}, later steps {later.count}")
     return losses
 
 

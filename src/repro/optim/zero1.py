@@ -489,10 +489,16 @@ def _bucketed_allgather(local, params, flags, axis_names,
 
 def zero1_step(loss_and_grad: Callable, params, opt: Zero1State, batch, *,
                axis_names: Sequence[str], opt_cfg: adamw.AdamWConfig,
-               sync: GradSyncConfig):
+               sync: GradSyncConfig, constrain: Callable | None = None):
     """One manual-region training step (inside shard_map over the data
     axes; the model axis stays auto/GSPMD).  Returns (params', opt',
-    metrics)."""
+    metrics).
+
+    ``constrain`` (tree -> tree) pins the reduced gradient shards to the
+    parameters' model-axis sharding.  The grad-norm sums and the AdamW
+    update are then partitioned the same way whether the sync ran per
+    leaf or bucketed, so the two trajectories stay bitwise equal under
+    tensor parallelism."""
     loss, grads = loss_and_grad(params, batch)
     world = 1
     for a in axis_names:
@@ -543,6 +549,8 @@ def zero1_step(loss_and_grad: Callable, params, opt: Zero1State, batch, *,
         g_red = jax.tree.map(reduce_one, grads, flags)
         new_ef = opt.ef
 
+    if constrain is not None:
+        g_red = constrain(g_red)
     # --- global grad norm: shards partition the reduced grad exactly, so
     # one psum of the summed shard sq-norms + the (replicated) tiny-leaf
     # sq-norms gives the global norm ---

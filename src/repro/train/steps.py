@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import compat
 from repro.models import ModelApi, ShardingRecipe, make_param_specs
@@ -36,12 +37,16 @@ from repro.optim.zero1 import (GradSyncConfig, Zero1State, init_zero1_state,
 
 @dataclass
 class BuiltStep:
+    """``param_sharding`` (params -> sharding tree) is where the step
+    returns the params; placing them there before step 0 (as
+    ``launch.bootstrap.build_session`` does) keeps step 1 on step 0's
+    compiled program.  ``opt_spec`` is the same for the optimizer state."""
+
     step_fn: Callable          # (params, opt, batch) -> (params, opt, metrics)
     init_opt: Callable         # (params) -> opt state (matching sharding)
-    in_shardings: Any = None   # for dry-run lowering
     batch_spec: Any = None
-    param_spec_tree: Any = None
     opt_spec: Any = None
+    param_sharding: Any = None
 
 
 def flat_param_len(params, world: int) -> int:
@@ -90,7 +95,10 @@ def build_single(model: ModelApi, opt_cfg: AdamWConfig) -> BuiltStep:
                                      "lr": adamw_mod.lr_at(opt_cfg,
                                                            new_opt.step)}
 
-    return BuiltStep(step_fn=step_fn, init_opt=init_tree_state)
+    device = SingleDeviceSharding(jax.devices()[0])
+    return BuiltStep(step_fn=step_fn, init_opt=init_tree_state,
+                     opt_spec=lambda params: device,
+                     param_sharding=lambda params: device)
 
 
 # ---------------------------------------------------------------------------
@@ -124,9 +132,9 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
     # assert every bucket's segments are well-formed.  The RS/AG plans
     # verified above are the ones each bucket executes (plan geometry is
     # shape-independent, so one cached plan serves every bucket).
+    abs_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if sync.bucket_bytes is not None:
         from repro.optim.zero1 import is_zero_leaf, plan_grad_buckets
-        abs_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         zshapes = [l.shape for l in jax.tree.leaves(abs_params)
                    if is_zero_leaf(l.shape, world, sync.min_shard_numel)]
         buckets = plan_grad_buckets(zshapes, world, sync.bucket_bytes,
@@ -164,29 +172,35 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
                                       axis_name=ep_axis))
 
     # Inside the manual region the data axes are already per-shard: the
-    # inner model must only constrain over the AUTO (model) axis.  On JAX
-    # builds whose XLA cannot partition ppermutes inside a manual subgroup
-    # (0.4.x — see compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES) the whole
-    # step instead runs manual over EVERY mesh axis: model-axis ranks hold
-    # full replicas (TP constraints dropped), while the data-axis circulant
-    # collectives — the part under test — are unchanged.  ep dispatch
-    # likewise needs its exchange axis manual, so it always takes the
+    # inner model must only constrain over the AUTO (model) axis.  ep
+    # dispatch needs its exchange axis manual, so it takes the
     # fully-manual route (expert weights replicated per rank; each rank
-    # slices its own experts inside the region).
+    # slices its own experts inside the region).  So does a model axis of
+    # size 1, where both routes compute the same thing: the TPU compiler
+    # cannot partition a Pallas kernel over an AUTO axis, so the fused
+    # round kernels compile only in a fully-manual region.
     from dataclasses import replace as _dc_replace
     from repro.models import build as _build_model
-    if compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES and not ep:
-        inner_recipe = _dc_replace(recipe, data_axes=())
-        manual_axes = set(recipe.data_axes)
-    else:
+    if ep or mesh.shape[recipe.model_axis] == 1:
         inner_recipe = None
         manual_axes = None  # full manual
+    else:
+        inner_recipe = _dc_replace(recipe, data_axes=())
+        manual_axes = set(recipe.data_axes)
     inner_model = _build_model(model.cfg, recipe=inner_recipe, remat=remat)
+
+    def constrain(tree):
+        """Reduced grad shards -> the params' model-axis sharding."""
+        if inner_recipe is None:
+            return tree
+        return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                            inner_model.param_specs(tree))
 
     def inner(params, opt, batch):
         return zero1_step(
             jax.value_and_grad(inner_model.loss), params, opt, batch,
-            axis_names=collective_axes, opt_cfg=opt_cfg, sync=sync)
+            axis_names=collective_axes, opt_cfg=opt_cfg, sync=sync,
+            constrain=constrain)
 
     # Manual-axis specs: params replicated over data axes (model axis is
     # auto — rides on the arrays' NamedShardings); batch sharded over data;
@@ -206,12 +220,28 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
     def opt_specs_for(params):
         return zero1_state_specs(params, world, sync, collective_axes)
 
-    # NB: must run under jit — JAX 0.8.2's EAGER shard_map dispatch with
+    def opt_sharding(params):
+        ospecs = opt_specs_for(params)
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), ospecs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def param_sharding(params):
+        # ep regions are fully manual over replicated params; otherwise
+        # the recipe's tensor-parallel specs over the model axis.
+        if ep:
+            return jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
+        return _recipe_sharding(model, mesh, recipe, params)
+
+    # The step returns params and optimizer state at the shardings it
+    # declares (``param_sharding``/``opt_sharding``), so inputs placed
+    # there before step 0 make every later step hit step 0's program.
+    out_shardings = (param_sharding(abs_params), opt_sharding(abs_params),
+                     NamedSharding(mesh, P()))
+
+    # NB: must run under jit — EAGER shard_map dispatch with
     # check_vma=False + partial-auto axes trips an internal _unmatch spec
     # check (it builds P(all mesh axes) but validates against manual-only).
-    # check_vma=False is also what lets sync.use_fused_kernel route the
-    # collectives through pallas_call (no replication rule on 0.4.x).
-    @jax.jit
+    @functools.partial(jax.jit, out_shardings=out_shardings)
     def step_fn(params, opt, batch):
         ospecs = opt_specs_for(params)
         f = compat.shard_map(
@@ -227,15 +257,11 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
     def init_opt(params):
         return init_zero1_state(params, world, sync)
 
-    def opt_sharding(params):
-        ospecs = opt_specs_for(params)
-        return jax.tree.map(lambda s: NamedSharding(mesh, s), ospecs,
-                            is_leaf=lambda x: isinstance(x, P))
-
     return BuiltStep(
         step_fn=step_fn, init_opt=init_opt,
         batch_spec=batch_spec,
         opt_spec=opt_sharding,
+        param_sharding=param_sharding,
     )
 
 
@@ -247,7 +273,18 @@ def build_fsdp_auto(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
                     opt_cfg: AdamWConfig) -> BuiltStep:
     batch_spec = P(recipe.data_axes)
 
-    @jax.jit
+    def param_sharding(params):
+        return _recipe_sharding(model, mesh, recipe, params)
+
+    def opt_sharding(params):
+        ps = param_sharding(params)  # m/v shard like params
+        return TreeAdamState(m=ps, v=ps, step=NamedSharding(mesh, P()))
+
+    abs_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    @functools.partial(jax.jit, out_shardings=(
+        param_sharding(abs_params), opt_sharding(abs_params),
+        NamedSharding(mesh, P())))
     def step_fn(params, opt, batch):
         loss, grads = jax.value_and_grad(model.loss)(params, batch)
         new_params, new_opt, gnorm = update_tree(opt_cfg, opt, grads, params)
@@ -256,7 +293,17 @@ def build_fsdp_auto(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
                                                            new_opt.step)}
 
     return BuiltStep(step_fn=step_fn, init_opt=init_tree_state,
-                     batch_spec=batch_spec)
+                     batch_spec=batch_spec, opt_spec=opt_sharding,
+                     param_sharding=param_sharding)
+
+
+def _recipe_sharding(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
+                     params):
+    """NamedShardings of the recipe's param specs, with every axis that
+    does not divide its dimension dropped (``launch.mesh.sanitize_specs``)."""
+    from repro.launch.mesh import named, sanitize_specs
+    return named(mesh, sanitize_specs(mesh, model.param_specs(params), params,
+                                      model_axis=recipe.model_axis))
 
 
 def build(mode: str, model: ModelApi, opt_cfg: AdamWConfig,

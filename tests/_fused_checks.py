@@ -37,8 +37,8 @@ p = NDEV
 
 
 def run1(fn, x_global):
-    """check_vma=False: pallas_call has no shard_map replication rule on
-    0.4.x; numerics are asserted below instead."""
+    """check_vma=False: the replication checker is off for the
+    pallas_call rows; numerics are asserted below instead."""
     f = jax.jit(compat.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
                                  in_specs=(P("x"),), out_specs=P("x"),
                                  check_vma=False))
