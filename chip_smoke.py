@@ -33,7 +33,15 @@ TRAIN_SEQ, TRAIN_BATCH, TRAIN_STEPS = 256, 4, 6
 TRAIN_LR, TRAIN_WARMUP = 1e-3, 1
 DP4_BATCH, DP4_STEPS = 8, 3
 BUCKET_BYTES = 25_000_000          # grad-sync bucket (launch.train example)
-EMBED_COLS = 2048                  # qwen3-1.7b d_model: one embedding row
+# The ZeRO-1 dp=4 losses of each circulant form against grad_sync="xla".
+# Exact forms sum the same gradients in another order, and the bf16
+# parameter update can turn a last-bit difference into one bf16 ulp of a
+# weight: 1e-3 relative.  The int8 wire rounds each gradient element to
+# 1/254 of its 512-element group's largest; the CPU check of the same
+# step without error feedback (tests/_zero1_checks.py) stays within 0.35%
+# of the exact losses, so 2e-2 relative leaves six times that while a
+# wrong scale or a lost round, which moves the loss by whole units, fails.
+EXACT_LOSS_RTOL, INT8_LOSS_RTOL = 1e-3, 2e-2
 
 SERVE_ARCH = "internlm2-1.8b"
 # Mixed lengths, three of them distinct: the Scheduler compiles its B=1
@@ -131,18 +139,15 @@ def _round_geometries(p: int) -> list[tuple[int, int, int]]:
     return sorted(out)
 
 
-def default_widths() -> dict:
-    """Block widths (elements) at which qwen3-1.7b's collectives call the
-    round kernels at p ranks: one 25 MB f32 gradient bucket, and one row
-    of the embedding table (d_model columns)."""
-    return {"bucket": lambda p: -(-BUCKET_BYTES // 4 // p),
-            "embedding_row": lambda p: EMBED_COLS}
-
-
-def phase_kernels(widths=None, ps=(2, 3, 4)) -> None:
+def phase_kernels() -> None:
     """Compile the five Pallas round kernels at the gradient sync's real
     widths for every round geometry at p ranks; each must appear as a
     ``tpu_custom_call`` and match its ``kernels/ref.py`` oracle bitwise.
+
+    Block widths (columns) of qwen3-1.7b's gradient sync at p ranks: one
+    25 MB f32 bucket, and the embedding leaf (vocab x d_model / p, how
+    ZeRO-1 lays it out); plus 2048 columns, which the int8 kernels take
+    as a single column tile (``quantize.tile_cols``).
 
     The cases of one (p, width, geometry) -- and the quantize/dequant and
     the permute cases of one (p, width) -- run as one program that runs
@@ -158,9 +163,10 @@ def phase_kernels(widths=None, ps=(2, 3, 4)) -> None:
                                permute_rows, quantize_rows, wire_ngroups)
     from repro.kernels import ref
     from repro.kernels.quantize import dequant_add
+    from repro.launch.bootstrap import resolve_cfg
 
+    cfg = resolve_cfg(TRAIN_ARCH)
     on_tpu = jax.default_backend() == "tpu"
-    widths = widths or default_widths()
     rng = np.random.default_rng(0)
 
     def make(shape, kind):
@@ -215,11 +221,13 @@ def phase_kernels(widths=None, ps=(2, 3, 4)) -> None:
         if bad:
             raise AssertionError(f"differ from their oracles: {bad}")
 
-    for p in ps:
+    for p in (2, 3, 4):
         geoms = _round_geometries(p)
         log(f" p={p} round geometries (lo, nb, next_lo) = {geoms}")
-        for wname, width in widths.items():
-            cols = width(p)
+        widths = {"bucket": -(-BUCKET_BYTES // 4 // p),
+                  "embedding_leaf": cfg.vocab_size * cfg.d_model // p,
+                  "single_tile": 2048}
+        for wname, cols in widths.items():
             cp = cols + (-cols) % DEFAULT_GROUP
             for lo, nb, nl in geoms:
                 rnd = dict(nb=nb, next_lo=nl)
@@ -290,7 +298,11 @@ def _run_training(label: str, *, steps: int, **session_kw) -> list[float]:
                 losses.append(float(bootstrap.run_step(sess, step)["loss"]))
                 log(f"  {label}: step {step} loss={losses[-1]!r} "
                     f"host_s={time.time() - t0:.3f}")
-    log(f"  {label}: compiles after step 0: {later.count}")
+    log(f"  {label}: compiles after step 0: {later.count} {_memory()}")
+    # Free the session's device buffers now, so the next session starts
+    # on an empty chip.
+    for x in jax.tree.leaves((sess.params, sess.opt)):
+        x.delete()
     if not all(math.isfinite(v) for v in losses):
         raise AssertionError(f"{label}: non-finite loss {losses}")
     if later.count:
@@ -298,55 +310,56 @@ def _run_training(label: str, *, steps: int, **session_kw) -> list[float]:
     return losses
 
 
-def phase_trainer(arch=TRAIN_ARCH, n_layers=TRAIN_LAYERS, seq_len=TRAIN_SEQ,
-                  global_batch=TRAIN_BATCH, steps=TRAIN_STEPS,
-                  scale_down=False) -> None:
+def phase_trainer() -> None:
     """qwen3-1.7b at its published widths on one chip (mode single)."""
-    losses = _run_training("trainer", steps=steps, arch=arch,
-                           n_layers=n_layers, scale_down=scale_down,
-                           seq_len=seq_len, global_batch=global_batch, dp=1)
+    losses = _run_training("trainer", steps=TRAIN_STEPS, arch=TRAIN_ARCH,
+                           n_layers=TRAIN_LAYERS, seq_len=TRAIN_SEQ,
+                           global_batch=TRAIN_BATCH, dp=1)
     if not losses[-1] < losses[0]:
         raise AssertionError(f"trainer: loss did not fall {losses}")
 
 
-def phase_trainer_dp4(arch=TRAIN_ARCH, n_layers=TRAIN_LAYERS,
-                      seq_len=TRAIN_SEQ, global_batch=DP4_BATCH,
-                      steps=DP4_STEPS, scale_down=False) -> None:
-    """The ZeRO-1 dp=4 step with single-shot circulant grad sync against
-    ``grad_sync="xla"``.  Tolerance: the circulant sync sums the same
-    gradients in another order, and the bf16 parameter update can turn a
-    last-bit difference into one bf16 ulp of a weight, so the losses
-    agree to 1e-3 relative.
+def phase_trainer_dp4() -> None:
+    """The ZeRO-1 dp=4 step with circulant grad sync in three forms --
+    single-shot, bucketed (25 MB buckets) and the int8 wire -- against
+    ``grad_sync="xla"`` (tolerances above).
 
-    The bucketed and int8+EF forms are left out: compiled for a described
-    v5e:2x2 at these widths they took 1071 s (25 MB buckets, over 26 GB
-    of host memory) and 197 s, against 40 s for single-shot."""
+    The int8 form runs without error feedback: the EF residual is a
+    float32 copy of every gradient leaf per rank, and with the step's
+    state not donated the int8+EF step needs 5.99 GB of arguments, as
+    much again for its outputs and 7.30 GB of temporaries per chip
+    (compiled for a described v5e:2x2), more than a v5e's 16 GB."""
     import gc
 
-    common = dict(arch=arch, n_layers=n_layers, scale_down=scale_down,
-                  seq_len=seq_len, global_batch=global_batch, dp=4, mp=1)
+    common = dict(arch=TRAIN_ARCH, n_layers=TRAIN_LAYERS, seq_len=TRAIN_SEQ,
+                  global_batch=DP4_BATCH, dp=4, mp=1)
     forms = {
-        "xla": dict(grad_sync="xla"),
-        "circulant": dict(grad_sync="circulant"),
+        "xla": (dict(grad_sync="xla"), None),
+        "circulant": (dict(grad_sync="circulant"), EXACT_LOSS_RTOL),
+        "circulant+bucketed": (dict(grad_sync="circulant",
+                                    bucket_bytes=BUCKET_BYTES),
+                               EXACT_LOSS_RTOL),
+        "circulant+int8": (dict(grad_sync="circulant", wire_dtype="int8",
+                                error_feedback=False), INT8_LOSS_RTOL),
     }
-    losses = {}
-    for name, kw in forms.items():
-        losses[name] = _run_training(name, steps=steps, **common, **kw)
+    base = None
+    for name, (kw, rtol) in forms.items():
+        losses = _run_training(name, steps=DP4_STEPS, **common, **kw)
         gc.collect()
-    base = losses["xla"]
-    for name, got in losses.items():
-        if name == "xla":
+        if base is None:
+            base = losses
             continue
-        for step, (g, b) in enumerate(zip(got, base)):
-            if not abs(g - b) <= 1e-3 * abs(b):
+        for step, (g, b) in enumerate(zip(losses, base)):
+            if not abs(g - b) <= rtol * abs(b):
                 raise AssertionError(
-                    f"{name} step {step}: loss {g!r} vs xla {b!r}")
+                    f"{name} step {step}: loss {g!r} vs xla {b!r} "
+                    f"(rtol {rtol})")
         log(f"  {name} vs xla: max |dloss| = "
-            f"{max(abs(g - b) for g, b in zip(got, base))!r}")
+            f"{max(abs(g - b) for g, b in zip(losses, base))!r} "
+            f"(rtol {rtol})")
 
 
-def phase_server(arch=SERVE_ARCH, prompt_lens=PROMPT_LENS, max_new=MAX_NEW,
-                 max_len=SERVE_MAX_LEN, scale_down=False) -> None:
+def phase_server() -> None:
     """internlm2-1.8b through the continuous-batching Scheduler, its
     logits checked against the float32 reference of the same weights."""
     import jax
@@ -359,8 +372,8 @@ def phase_server(arch=SERVE_ARCH, prompt_lens=PROMPT_LENS, max_new=MAX_NEW,
     from repro.serve import Scheduler
 
     t0 = time.time()
-    sess = build_serve_session(arch=arch, max_len=max_len,
-                               scale_down=scale_down)
+    prompt_lens, max_new, max_len = PROMPT_LENS, MAX_NEW, SERVE_MAX_LEN
+    sess = build_serve_session(arch=SERVE_ARCH, max_len=max_len)
     cfg, eng = sess.cfg, sess.engine
     n_params = sum(int(x.size) for x in jax.tree.leaves(sess.params))
     log(f"  {cfg.name} depth {cfg.n_layers} d_model={cfg.d_model} "
@@ -443,7 +456,7 @@ def phase_server(arch=SERVE_ARCH, prompt_lens=PROMPT_LENS, max_new=MAX_NEW,
                              "logit bound")
 
 
-def phase_collectives(ps=(2, 3, 4), blk=COLL_BLK) -> None:
+def phase_collectives() -> None:
     """RS, AG, AR through the dispatchers (circulant jnp, fused, fused +
     int8 wire) against the xla backend and a host reference, with the
     collective-permute count of each compiled program; then the
@@ -464,7 +477,8 @@ def phase_collectives(ps=(2, 3, 4), blk=COLL_BLK) -> None:
              "fused": CollectiveSpec(use_fused_kernel=True),
              "fused+int8": CollectiveSpec(use_fused_kernel=True,
                                           wire_dtype="int8")}
-    for p in ps:
+    blk = COLL_BLK
+    for p in (2, 3, 4):
         devices = jax.devices()[:p]
         mesh = compat.make_mesh((p,), (conf.AXIS,), devices=devices)
         log(f"  p={p} mesh devices={[d.id for d in devices]}")

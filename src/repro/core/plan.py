@@ -914,6 +914,31 @@ def _rotated_blocks(plan: CollectivePlan, x: Array) -> Array:
     return jnp.roll(plan.layout_for(x.shape[0]).as_blocks(x), -r, axis=0)
 
 
+#: One TPU vector lane row: the minor width of the rotated-row view.
+_LANES = 128
+
+
+def _roll_rows(x: Array, shift) -> Array:
+    """``jnp.roll(x, shift, axis=0)`` for a (p, ...) array, rotated through
+    a (p, numel / 128, 128) view when the rows allow it.  The TPU
+    compiler relays out a rotated many-dimensional block, and a leaf
+    reshaped straight from (rows, d) to (p, rows / p * d), row by row:
+    minutes of compile time at an embedding's row count."""
+    if int(np.prod(x.shape[1:])) % _LANES:
+        return jnp.roll(x, shift, axis=0)
+    return jnp.roll(x.reshape(x.shape[0], -1, _LANES), shift,
+                    axis=0).reshape(x.shape)
+
+
+def _rotated_rows(plan: CollectivePlan, x: Array) -> tuple[Array, tuple]:
+    """:func:`_rotated_blocks` as a (p, block numel) matrix, and the block
+    shape."""
+    r = lax.axis_index(plan.axis_name)
+    blocks = plan.layout_for(x.shape[0]).as_blocks(x)
+    rows = blocks.reshape(plan.p, -1)
+    return _roll_rows(rows, -r), blocks.shape[1:]
+
+
 class _RsJnp:
     """Algorithm 1's rounds, plain jnp ops (always available).
 
@@ -963,10 +988,9 @@ class _RsFused:
 
     @staticmethod
     def begin(plan, st, x, compress, decompress):
-        R = _rotated_blocks(plan, x)
-        R2 = R.reshape(plan.p, -1)
+        R2, blk_shape = _rotated_rows(plan, x)
         first = plan.rs_rounds[0]
-        st.data.update(blk_shape=R.shape[1:],
+        st.data.update(blk_shape=blk_shape,
                        live=R2[: first.lo],
                        send=R2[first.lo: first.hi],
                        compress=compress, decompress=decompress)
@@ -1015,8 +1039,8 @@ class _RsWire:
     @staticmethod
     def begin(plan, st, x, compress, decompress):
         fused = plan.backend == "fused+int8"
-        R = _rotated_blocks(plan, x)
-        R2 = R.reshape(plan.p, -1).astype(jnp.float32)
+        R2, blk_shape = _rotated_rows(plan, x)
+        R2 = R2.astype(jnp.float32)
         cols = R2.shape[1]
         g = min(plan.spec.wire_group, cols)
         R2 = pad2d(R2, 1, g)
@@ -1026,7 +1050,7 @@ class _RsWire:
             codes, scales = quantize_rows(first, group=g)
         else:
             codes, scales = _kref.quantize_ref(first, group=g)
-        st.data.update(blk_shape=R.shape[1:], out_dtype=R.dtype,
+        st.data.update(blk_shape=blk_shape, out_dtype=x.dtype,
                        cols=cols, g=g, fused=fused,
                        live=R2[: first_round.lo],
                        wire=pack_wire(codes, scales))
@@ -1154,7 +1178,7 @@ class _AgWire:
         vals = _kref.dequant_ref(codes, scales, group=g)  # (p, cols_pad) f32
         if cols != st.data["padded_cols"]:
             vals = vals[:, :cols]
-        out = jnp.roll(vals, st.data["r"], axis=0)  # out[j] = block of j
+        out = _roll_rows(vals, st.data["r"])  # out[j] = block of j
         return (out.reshape(plan.p * blk[0], *blk[1:])
                 .astype(st.data["out_dtype"]))
 

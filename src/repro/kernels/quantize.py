@@ -96,8 +96,9 @@ def tile_cols(cols: int, g: int) -> tuple[int, int]:
 
 def group_scales(x: jax.Array, g: int) -> tuple[jax.Array, jax.Array]:
     """(rows, k*g) f32 -> (grouped (rows, k, g), scales (rows, k)): the
-    per-group amax scale, one expression shared by the kernels and the
-    jnp oracle (``ref.quantize_ref``) so both round identically."""
+    per-group amax scale, one expression shared by the quantizing
+    kernels; ``ref.quantize_ref`` writes the same expression out on its
+    own, so a fault here shows as a bitwise difference."""
     rows, cols = x.shape
     xg = x.reshape(rows, cols // g, g)
     amax = jnp.max(jnp.abs(xg), axis=2)
@@ -211,20 +212,21 @@ def dequant_add(
 def pack_wire(codes: jax.Array, scales: jax.Array) -> jax.Array:
     """Pack int8 codes (rows, cols) + f32 scales (rows, ng) into one
     contiguous int8 buffer (rows, cols + 4*ng) — the compressed round's
-    single ppermute payload."""
-    rows, ng = scales.shape
+    single ppermute payload.  The scale bytes follow the codes one byte
+    plane at a time (byte k of every scale, k = 0..3): no array gets a
+    minor dimension of 4, which the TPU compiler would relay out row by
+    row."""
     u = lax.bitcast_convert_type(scales, jnp.uint32)          # (rows, ng)
-    sb = jnp.stack([(u >> (8 * k)) & 0xFF for k in range(4)],
-                   axis=-1).astype(jnp.uint8)                 # (rows, ng, 4)
-    sb = lax.bitcast_convert_type(sb.reshape(rows, 4 * ng), jnp.int8)
-    return jnp.concatenate([codes, sb], axis=1)
+    planes = [lax.bitcast_convert_type(
+        ((u >> (8 * k)) & 0xFF).astype(jnp.uint8), jnp.int8)
+        for k in range(4)]
+    return jnp.concatenate([codes, *planes], axis=1)
 
 
 def unpack_wire(wire: jax.Array, cols: int, *,
                 group: int = DEFAULT_GROUP) -> tuple[jax.Array, jax.Array]:
     """Inverse of ``pack_wire``: split a (rows, wire_width(cols, group))
     int8 buffer back into codes (rows, cols) and f32 scales (rows, ng)."""
-    rows = wire.shape[0]
     ng = wire_ngroups(cols, group)
     if wire.shape[1] != cols + 4 * ng:
         raise ValueError(
@@ -232,6 +234,6 @@ def unpack_wire(wire: jax.Array, cols: int, *,
             f"(cols={cols}, group={group})")
     codes = wire[:, :cols]
     sb = lax.bitcast_convert_type(wire[:, cols:], jnp.uint8)
-    sb = sb.reshape(rows, ng, 4).astype(jnp.uint32)
-    u = sum(sb[..., k] << (8 * k) for k in range(4)).astype(jnp.uint32)
+    u = sum(sb[:, k * ng:(k + 1) * ng].astype(jnp.uint32) << (8 * k)
+            for k in range(4)).astype(jnp.uint32)
     return codes, lax.bitcast_convert_type(u, jnp.float32)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 # Shared with the kernels — the bitwise kernel-vs-oracle contract depends
 # on both sides using the exact same constants and op shapes.
-from .quantize import group_scales
+from .quantize import _EPS, _INV127
 
 
 def block_reduce_ref(a: jax.Array, b: jax.Array, *, op: str = "add") -> jax.Array:
@@ -33,7 +33,9 @@ def fused_round_ref(live: jax.Array, received: jax.Array, *, nb: int,
 
 
 def permute_rows_ref(x: jax.Array, perm) -> jax.Array:
-    return x[jnp.asarray(tuple(int(i) for i in perm))]
+    # Static row slices: a gather with a constant index vector compiles
+    # for minutes on a TPU at an embedding leaf's width.
+    return jnp.concatenate([x[int(i):int(i) + 1] for i in perm], axis=0)
 
 
 def _pad_cols(x: jax.Array, g: int) -> jax.Array:
@@ -46,7 +48,9 @@ def quantize_ref(x: jax.Array, *, group: int = 512
     rows, cols = x.shape
     g = min(group, cols)
     xp = _pad_cols(x.astype(jnp.float32), g)
-    xg, scale = group_scales(xp, g)                        # scale (rows, ng)
+    xg = xp.reshape(rows, -1, g)
+    amax = jnp.max(jnp.abs(xg), axis=2)                    # (rows, ng)
+    scale = amax * _INV127 + _EPS
     q = jnp.clip(jnp.round(xg / scale[..., None]), -127, 127)
     codes = q.reshape(rows, xp.shape[1]).astype(jnp.int8)
     return codes[:, :cols], scale

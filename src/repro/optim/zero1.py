@@ -42,6 +42,7 @@ lin = r0 * p1 + r1; the matching hierarchical AG reassembles exactly.
 from __future__ import annotations
 
 import functools
+import math
 import warnings
 from dataclasses import dataclass
 from typing import Callable, NamedTuple, Sequence
@@ -355,28 +356,53 @@ def _zero_leaf_meta(flat_g, flat_flags):
     return zero_idx, rn
 
 
+#: Minor dimension of the bucket block matrices: one TPU vector lane row.
+_LANES = 128
+
+
+def _bucket_lanes(shapes, world: int, bucket_widths) -> int:
+    """Minor width ``c`` of every bucket array: the largest divisor of one
+    lane row that divides each leaf's shard numel, each multi-dimensional
+    leaf's row numel and each bucket width (128 at qwen3-1.7b's shapes).
+    Each leaf row is then a whole number of ``c``-wide rows.  Under tensor
+    parallelism that keeps the bucketed step bitwise equal to the per-leaf
+    one (``tests/_zero1_checks.py``): ``c``-wide rows that cut across leaf
+    rows changed the grad norm's partial sums there."""
+    widths = [(s[0] + (-s[0]) % world) // world
+              * int(np.prod(s[1:], dtype=np.int64)) for s in shapes]
+    rows = [int(np.prod(s[1:], dtype=np.int64)) for s in shapes
+            if len(s) > 1]
+    return math.gcd(_LANES, *widths, *rows, *bucket_widths)
+
+
 def _bucket_widths(buckets, zero_idx, rn):
     """Per-bucket column width (shard numel) in the global block matrix."""
     return [sum((hi - lo) * rn[zero_idx[li]] for (li, lo, hi) in b)
             for b in buckets]
 
 
-def _bucket_vectors(blocks, buckets, zero_idx, rn):
-    """Assemble one flat per-bucket vector, interleaved block-major so
-    block ``lin`` of the vector is rank ``lin``'s shard data — the layout
-    the circulant RS/AG block partition expects.
+def _bucket_vectors(blocks, widths, zero_idx):
+    """Assemble each bucket as a ``(world, w / c, c)`` block matrix whose
+    row ``lin`` is rank ``lin``'s shard data -- the layout the circulant
+    RS/AG block partition expects.  ``blocks[i]`` is leaf ``i`` as a
+    ``(world, R * rn / c, c)`` block matrix, ``widths`` the bucket widths
+    (``c`` from :func:`_bucket_lanes`).
 
     The partitioner walks leaves and shard rows in order, so every
-    bucket is a CONTIGUOUS column range of the global ``(world, Wtot)``
-    block matrix: one concatenate builds the matrix, then each bucket is
-    a single slice + reshape (op count matters — assembly sits on the
-    training step's critical path)."""
+    bucket is a CONTIGUOUS range of the global ``(world, Wtot / c, c)``
+    block matrix: one concatenate builds it, then each bucket is a single
+    slice (op count matters -- assembly sits on the training step's
+    critical path).  The minor dimension stays ``c`` throughout: the TPU
+    compiler relays out a reshape from a leaf's ``(rows, d)`` to a flat
+    ``(world, rows / world * d)`` row by row, which costs minutes of
+    compile time at an embedding's row count."""
     G = (blocks[zero_idx[0]] if len(zero_idx) == 1 else
          jnp.concatenate([blocks[i] for i in zero_idx], axis=1))
+    c = G.shape[2]
     vecs, off = [], 0
-    for w in _bucket_widths(buckets, zero_idx, rn):
-        vecs.append(G[:, off:off + w].reshape(-1))
-        off += w
+    for w in widths:
+        vecs.append(G[:, off:off + w // c])
+        off += w // c
     return vecs
 
 
@@ -397,6 +423,11 @@ def _bucketed_reduce(grads, flags, ef, axis_names, sync: GradSyncConfig,
     flat_flags = jax.tree.leaves(flags)
     flat_ef = jax.tree.leaves(ef) if ef is not None else [None] * len(flat_g)
     zero_idx, rn = _zero_leaf_meta(flat_g, flat_flags)
+    zshapes = [flat_g[i].shape for i in zero_idx]
+    buckets = plan_grad_buckets(zshapes, world, sync.bucket_bytes,
+                                jnp.dtype(rs_dt).itemsize)
+    widths = _bucket_widths(buckets, zero_idx, rn)
+    c = _bucket_lanes(zshapes, world, widths)
     zset = set(zero_idx)
     out: list = [None] * len(flat_g)
     new_ef = list(flat_ef)
@@ -412,25 +443,22 @@ def _bucketed_reduce(grads, flags, ef, axis_names, sync: GradSyncConfig,
             new_ef[i] = err[None]
             g = q
         gp = _pad_lead(g.astype(rs_dt), world)
-        blocks[i] = gp.reshape(world, -1)
-    buckets = plan_grad_buckets([flat_g[i].shape for i in zero_idx], world,
-                                sync.bucket_bytes,
-                                jnp.dtype(rs_dt).itemsize)
-    vecs = _bucket_vectors(blocks, buckets, zero_idx, rn)
+        blocks[i] = gp.reshape(world, -1, c)
+    vecs = _bucket_vectors(blocks, widths, zero_idx)
     spec = sync.rs_spec()
     for ax in axis_names:
         vecs = C.reduce_scatter_pipelined(vecs, ax, spec=spec)
-    # Each bucket's RS result is this rank's contiguous column range of
-    # the global block matrix, so concatenating the bucket results in
-    # order gives the rank's full shard vector; per-leaf slices then
+    # Each bucket's RS result (1, w_b / c, c) is this rank's contiguous
+    # range of the global block matrix, so concatenating the bucket
+    # results in order gives the rank's full shard; per-leaf slices then
     # fall at the leaf block widths.  Single divide, then L slices.
-    own = vecs[0] if len(vecs) == 1 else jnp.concatenate(vecs)
-    own = (own / world).astype(jnp.float32)
+    own = vecs[0] if len(vecs) == 1 else jnp.concatenate(vecs, axis=1)
+    own = (own[0] / world).astype(jnp.float32)
     off = 0
     for i in zero_idx:
-        w = blocks[i].shape[1]
-        out[i] = own[off:off + w].reshape(-1, *flat_g[i].shape[1:])
-        off += w
+        n = blocks[i].shape[1]
+        out[i] = own[off:off + n].reshape(-1, *flat_g[i].shape[1:])
+        off += n
     g_red = jax.tree.unflatten(tdef, out)
     if ef is None:
         return g_red, None
@@ -453,37 +481,41 @@ def _bucketed_allgather(local, params, flags, axis_names,
     flat_p = jax.tree.leaves(params)
     flat_flags = jax.tree.leaves(flags)
     zero_idx, rn = _zero_leaf_meta(flat_p, flat_flags)
-    out = list(flat_l)
-    buckets = plan_grad_buckets([flat_p[i].shape for i in zero_idx], world,
-                                sync.bucket_bytes,
+    zshapes = [flat_p[i].shape for i in zero_idx]
+    buckets = plan_grad_buckets(zshapes, world, sync.bucket_bytes,
                                 jnp.dtype(sync.rs_dtype).itemsize)
-    # One flat local-shard vector in leaf order (mixed dtypes promote via
-    # result_type and cast back after transport — lossless round trips);
-    # each bucket is a contiguous slice of it (see _bucket_vectors).
+    widths = _bucket_widths(buckets, zero_idx, rn)
+    c = _bucket_lanes(zshapes, world, widths)
+    out = list(flat_l)
+    # The local shards of all leaves as one (Wtot / c, c) matrix in leaf
+    # order (mixed dtypes promote via result_type and cast back after
+    # transport — lossless round trips); each bucket is a contiguous row
+    # range of it (see _bucket_vectors).
     dt = jnp.result_type(*[flat_l[i].dtype for i in zero_idx])
-    lvec = (flat_l[zero_idx[0]].astype(dt).reshape(-1)
+    lvec = (flat_l[zero_idx[0]].astype(dt).reshape(-1, c)
             if len(zero_idx) == 1 else
-            jnp.concatenate([flat_l[i].astype(dt).reshape(-1)
+            jnp.concatenate([flat_l[i].astype(dt).reshape(-1, c)
                              for i in zero_idx]))
     vecs, off = [], 0
-    for w in _bucket_widths(buckets, zero_idx, rn):
-        vecs.append(lvec[off:off + w])
-        off += w
+    for w in widths:
+        vecs.append(lvec[off:off + w // c])
+        off += w // c
     spec = sync.ag_spec()
     for ax in reversed(list(axis_names)):
         vecs = C.allgather_pipelined(vecs, ax, spec=spec)
-    # Gathered bucket b is (world * w_b,) block-major; re-joining the
-    # buckets column-wise rebuilds the global (world, Wtot) block matrix,
-    # from which each leaf is one column-range slice.
-    G = (vecs[0].reshape(world, -1) if len(vecs) == 1 else
-         jnp.concatenate([v.reshape(world, -1) for v in vecs], axis=1))
+    # Gathered bucket b is (world * w_b / c, c), rank-major; re-joining
+    # the buckets along their second axis rebuilds the global
+    # (world, Wtot / c, c) block matrix, from which each leaf is one
+    # range of it.
+    G = (vecs[0].reshape(world, -1, c) if len(vecs) == 1 else
+         jnp.concatenate([v.reshape(world, -1, c) for v in vecs], axis=1))
     off = 0
     for i in zero_idx:
         ld = flat_p[i].shape[0]
-        w = (ld + (-ld) % world) // world * rn[i]
-        out[i] = (G[:, off:off + w].reshape(-1, *flat_p[i].shape[1:])[:ld]
+        n = (ld + (-ld) % world) // world * rn[i] // c
+        out[i] = (G[:, off:off + n].reshape(-1, *flat_p[i].shape[1:])[:ld]
                   .astype(flat_p[i].dtype))
-        off += w
+        off += n
     return jax.tree.unflatten(tdef, out)
 
 

@@ -105,9 +105,41 @@ def build_single(model: ModelApi, opt_cfg: AdamWConfig) -> BuiltStep:
 # zero1 (manual data axes via the paper's collectives)
 # ---------------------------------------------------------------------------
 
+def resolve_zero1_sync(sync: GradSyncConfig, *, auto_axes: bool,
+                       platform: str) -> GradSyncConfig:
+    """The grad-sync config a zero1 step runs on ``platform``.
+
+    The TPU compiler cannot partition a Pallas (Mosaic) kernel over an
+    Auto mesh axis, so when the step keeps its model axis Auto the
+    circulant rounds run in jnp on a TPU: auto selection
+    (``use_fused_kernel=None``) picks jnp there, and an explicit
+    ``use_fused_kernel=True`` is refused here rather than in the TPU
+    compiler.  Elsewhere the kernels run in interpret mode, which GSPMD
+    partitions like any other op."""
+    if not auto_axes or platform != "tpu":
+        return sync
+    if sync.use_fused_kernel:
+        raise ValueError(
+            "use_fused_kernel=True cannot run in a zero1 step whose model "
+            "axis stays Auto on a TPU: Mosaic kernels cannot be "
+            "automatically partitioned.  Use a model axis of 1, or "
+            "use_fused_kernel=None/False (jnp rounds).")
+    from dataclasses import replace
+    return replace(sync, use_fused_kernel=False)
+
+
 def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
                 opt_cfg: AdamWConfig, sync: GradSyncConfig,
                 remat: bool = True) -> BuiltStep:
+    # Expert-parallel MoE dispatch exchanges over cfg.ep_axis INSIDE the
+    # step, so that axis must be manual too; ep and a model axis of size
+    # 1 run the region fully manual (see the inner model below).
+    ep = (model.cfg.is_moe
+          and getattr(model.cfg, "moe_dispatch", "global") == "ep")
+    fully_manual = ep or mesh.shape[recipe.model_axis] == 1
+    sync = resolve_zero1_sync(sync, auto_axes=not fully_manual,
+                              platform=mesh.devices.flat[0].platform)
+
     # Collective order: fastest axis first (intra-pod before cross-pod) so
     # the full-volume first RS phase stays on fast links (DESIGN §2).
     collective_axes = tuple(reversed(recipe.data_axes))
@@ -154,11 +186,8 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
                     f"bucket partition covers {covered.get(li, 0)}/{rows} "
                     f"shard rows of leaf {li} {shape}")
 
-    # Expert-parallel MoE dispatch exchanges over cfg.ep_axis INSIDE the
-    # step, so that axis must be manual too — and its alltoall(v) plans
-    # can fail fast / pre-warm here, like the grad-sync plans above.
-    ep = (model.cfg.is_moe
-          and getattr(model.cfg, "moe_dispatch", "global") == "ep")
+    # The ep axis's alltoall(v) plans fail fast / pre-warm here, like
+    # the grad-sync plans above.
     if ep:
         ep_axis = model.cfg.ep_axis
         if ep_axis not in mesh.shape:
@@ -181,7 +210,7 @@ def build_zero1(model: ModelApi, mesh: Mesh, recipe: ShardingRecipe,
     # round kernels compile only in a fully-manual region.
     from dataclasses import replace as _dc_replace
     from repro.models import build as _build_model
-    if ep or mesh.shape[recipe.model_axis] == 1:
+    if fully_manual:
         inner_recipe = None
         manual_axes = None  # full manual
     else:

@@ -29,7 +29,7 @@ EMBED_COLS = 77_791_232
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = compat.tpu_topology("v5e:2x2")
@@ -38,7 +38,12 @@ def one_chip():
     # A compile for a described chip is written to the persistent cache
     # but cannot be read back without the chip: keep the cache off.
     with compat.persistent_cache_off():
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -86,3 +91,38 @@ CASES = {
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = CASES[name]
     assert "tpu_custom_call" in _compile(fn, one_chip, *shapes)
+
+
+def test_zero1_step_with_auto_model_axis_runs_jnp_rounds(v5e_2x2):
+    """On a dp=2 x mp=2 mesh the ZeRO-1 step keeps its model axis Auto,
+    where the TPU compiler cannot partition a Pallas kernel: an explicit
+    ``use_fused_kernel=True`` is refused when the step is built, and the
+    default (auto) step compiles with jnp rounds."""
+    from jax.sharding import NamedSharding
+
+    from repro.launch import bootstrap
+
+    kw = dict(arch="qwen3-1.7b", scale_down=True, seq_len=32,
+              global_batch=2, dp=2, mp=2, devices=v5e_2x2.devices,
+              init_state=False)
+    with pytest.raises(ValueError, match="Mosaic"):
+        bootstrap.build_session(use_fused_kernel=True, **kw)
+    sess = bootstrap.build_session(**kw)
+    built = sess.built
+    shapes = jax.eval_shape(sess.model.init, jax.random.PRNGKey(0))
+
+    def abstract(tree, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree, shardings)
+
+    params = abstract(shapes, built.param_sharding(shapes))
+    opt = abstract(jax.eval_shape(built.init_opt, shapes),
+                   built.opt_spec(shapes))
+    batch_sh = NamedSharding(sess.mesh, built.batch_spec)
+    batch = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=batch_sh),
+        sess.pipe.batch_at(0))
+    with compat.use_mesh(sess.mesh):
+        text = built.step_fn.lower(params, opt, batch).compile().as_text()
+    assert "collective-permute" in text
+    assert "tpu_custom_call" not in text

@@ -108,3 +108,25 @@ def test_zero1_end_to_end():
             f"zero1 checks failed:\n--- stdout ---\n{proc.stdout}\n"
             f"--- stderr ---\n{proc.stderr}")
     assert "ALL ZERO1 CHECKS PASSED" in proc.stdout
+
+
+@pytest.mark.parametrize("fused,auto_axes,platform,want", [
+    (None, True, "tpu", False),    # Auto model axis on a TPU: jnp rounds
+    (False, True, "tpu", False),
+    (None, False, "tpu", None),    # fully manual: auto selection stands
+    (True, False, "tpu", True),
+    (None, True, "cpu", None),     # interpret mode partitions anywhere
+    (True, True, "cpu", True),
+])
+def test_zero1_sync_fused_selection(fused, auto_axes, platform, want):
+    from repro.train.steps import resolve_zero1_sync
+    sync = resolve_zero1_sync(GradSyncConfig(use_fused_kernel=fused),
+                              auto_axes=auto_axes, platform=platform)
+    assert sync.use_fused_kernel is want
+
+
+def test_zero1_sync_refuses_fused_kernel_over_auto_axis_on_tpu():
+    from repro.train.steps import resolve_zero1_sync
+    with pytest.raises(ValueError, match="Mosaic kernels cannot be"):
+        resolve_zero1_sync(GradSyncConfig(use_fused_kernel=True),
+                           auto_axes=True, platform="tpu")
