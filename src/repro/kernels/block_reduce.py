@@ -62,5 +62,6 @@ def block_reduce(
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        name="block_reduce",
         interpret=interpret,
     )(a, b)

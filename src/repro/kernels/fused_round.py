@@ -157,7 +157,8 @@ def fused_round(
             ],
             "out_specs": out_specs,
         }
-    res = pl.pallas_call(kernel, out_shape=out_shape, **kw)(live, received)
+    res = pl.pallas_call(kernel, out_shape=out_shape, name="fused_round",
+                         **kw)(live, received)
     if final:
         return res, None
     return res[0], res[1]
@@ -287,8 +288,8 @@ def fused_round_dq(
             ],
             "out_specs": out_specs,
         }
-    res = pl.pallas_call(kernel, out_shape=out_shape, **kw)(
-        live, codes, scales)
+    res = pl.pallas_call(kernel, out_shape=out_shape, name="fused_round_dq",
+                         **kw)(live, codes, scales)
     if final:
         return res, None
     return res[0], (res[1], res[2])
@@ -339,5 +340,6 @@ def permute_rows(
     return pl.pallas_call(
         functools.partial(_permute_kernel, perm=perm),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="permute_rows",
         **kw,
     )(x)

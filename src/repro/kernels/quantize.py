@@ -146,6 +146,7 @@ def quantize(
             jax.ShapeDtypeStruct((rp, cp), jnp.int8),
             jax.ShapeDtypeStruct((rp, cp // g), jnp.float32),
         ],
+        name="quantize",
         interpret=interpret,
     )(xp)
     if (rp, cp) != (rows, cols):
@@ -198,6 +199,7 @@ def dequant_add(
                   pl.BlockSpec((rt, sct), lambda i, j: (i, j))],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rp, cp), acc.dtype),
+        name="dequant_add",
         interpret=interpret,
     )(accp, codesp, scalesp)
     if (rp, cp) != (rows, cols):

@@ -92,9 +92,8 @@ def main(argv=None):
         dt = time.time() - t0
         total = sum(len(done[r]) for r in rids)
         print(f"scheduler: {args.batch} requests, {total} tokens in "
-              f"{dt:.2f}s ({total / dt:.1f} tok/s incl. compile; "
-              f"{sched.n_decode_steps} decode steps, "
-              f"{sched.n_prefills} prefills)")
+              f"{dt:.2f}s ({total / dt:.1f} tok/s incl. compile)")
+        print(sched.stats.summary())
         for b, r in enumerate(rids[:2]):
             print(f"  req{r}: {done[r][:12].tolist()}")
         return done

@@ -531,7 +531,8 @@ def zero1_step(loss_and_grad: Callable, params, opt: Zero1State, batch, *,
     update are then partitioned the same way whether the sync ran per
     leaf or bucketed, so the two trajectories stay bitwise equal under
     tensor parallelism."""
-    loss, grads = loss_and_grad(params, batch)
+    with jax.named_scope("zero1.fwd_bwd"):
+        loss, grads = loss_and_grad(params, batch)
     world = 1
     for a in axis_names:
         world *= compat.axis_size(a)
@@ -551,92 +552,98 @@ def zero1_step(loss_and_grad: Callable, params, opt: Zero1State, batch, *,
             return out.astype(jnp.float32)
         return allreduce_leaf(g.astype(jnp.float32), axis_names, sync, world)
 
-    if bucketed:
-        # Bucketed, pipelined sync: bucket b's round-k ppermute overlaps
-        # bucket b+1's fold (see _bucketed_reduce; bucket_bytes=None
-        # keeps the per-leaf one-shot path below, bitwise-identical).
-        g_red, ef_out = _bucketed_reduce(
-            grads, flags, opt.ef if use_ef else None, axis_names, sync,
-            world, rs_dt)
-        new_ef = ef_out if use_ef else opt.ef
-    elif use_ef:
-        # Compressed sync with error feedback: compensate, quantize, and
-        # carry the rounding error (see ef_quantize).  ``e`` arrives as
-        # this rank's (1, *leaf) shard of the (world, *leaf) state.
-        def reduce_one_ef(g, flag, e):
+    with jax.named_scope("zero1.reduce_scatter"):
+        if bucketed:
+            # Bucketed, pipelined sync: bucket b's round-k ppermute
+            # overlaps bucket b+1's fold (see _bucketed_reduce;
+            # bucket_bytes=None keeps the per-leaf one-shot path below,
+            # bitwise-identical).
+            g_red, ef_out = _bucketed_reduce(
+                grads, flags, opt.ef if use_ef else None, axis_names, sync,
+                world, rs_dt)
+            new_ef = ef_out if use_ef else opt.ef
+        elif use_ef:
+            # Compressed sync with error feedback: compensate, quantize,
+            # and carry the rounding error (see ef_quantize).  ``e``
+            # arrives as this rank's (1, *leaf) shard of the (world,
+            # *leaf) state.
+            def reduce_one_ef(g, flag, e):
+                if flag and use_zero:
+                    q, err = ef_quantize(g, e[0], sync.quant_group)
+                    out = reduce_scatter_leaf(q.astype(rs_dt), axis_names,
+                                              sync, world)
+                    return out.astype(jnp.float32), err[None]
+                return (allreduce_leaf(g.astype(jnp.float32), axis_names,
+                                       sync, world), e)
+
+            pairs = jax.tree.map(reduce_one_ef, grads, flags, opt.ef)
+            ispair = lambda x: (isinstance(x, tuple) and len(x) == 2
+                                and not isinstance(x, jax.Array))
+            g_red = jax.tree.map(lambda o: o[0], pairs, is_leaf=ispair)
+            new_ef = jax.tree.map(lambda o: o[1], pairs, is_leaf=ispair)
+        else:
+            g_red = jax.tree.map(reduce_one, grads, flags)
+            new_ef = opt.ef
+        if constrain is not None:
+            g_red = constrain(g_red)
+    with jax.named_scope("zero1.adamw"):
+        # --- global grad norm: shards partition the reduced grad exactly, so
+        # one psum of the summed shard sq-norms + the (replicated) tiny-leaf
+        # sq-norms gives the global norm ---
+        flat_flags = jax.tree.leaves(flags)
+        flat_g = jax.tree.leaves(g_red)
+        shard_sq = sum((jnp.sum(jnp.square(g)) for g, f in
+                        zip(flat_g, flat_flags) if f and use_zero),
+                       start=jnp.zeros((), jnp.float32))
+        tiny_sq = sum((jnp.sum(jnp.square(g)) for g, f in
+                       zip(flat_g, flat_flags) if not (f and use_zero)),
+                      start=jnp.zeros((), jnp.float32))
+        for ax in axis_names:
+            shard_sq = lax.psum(shard_sq, ax)
+        gnorm = jnp.sqrt(shard_sq + tiny_sq)
+        scale = adamw.clip_scale_from_norm(opt_cfg, gnorm)
+
+        # --- AdamW on shards ---
+        step = opt.step + 1
+        t = step.astype(jnp.float32)
+        lr = adamw.lr_at(opt_cfg, step)
+        bc1 = 1 - opt_cfg.beta1 ** t
+        bc2 = 1 - opt_cfg.beta2 ** t
+
+        def update_one(p, g, m, v, flag):
             if flag and use_zero:
-                q, err = ef_quantize(g, e[0], sync.quant_group)
-                out = reduce_scatter_leaf(q.astype(rs_dt), axis_names,
-                                          sync, world)
-                return out.astype(jnp.float32), err[None]
-            return (allreduce_leaf(g.astype(jnp.float32), axis_names,
-                                   sync, world), e)
+                ld = p.shape[0]
+                p_pad = _pad_lead(p, world)
+                off, rows = shard_offset(p_pad.shape[0], axis_names)
+                p_loc = lax.dynamic_slice_in_dim(p_pad, off, rows, axis=0)
+            else:
+                p_loc = p
+            g = g * scale
+            m2 = opt_cfg.beta1 * m + (1 - opt_cfg.beta1) * g
+            v2 = opt_cfg.beta2 * v + (1 - opt_cfg.beta2) * g * g
+            delta = -lr * ((m2 / bc1) / (jnp.sqrt(v2 / bc2) + opt_cfg.eps)
+                           + opt_cfg.weight_decay * p_loc.astype(jnp.float32))
+            new_loc = (p_loc.astype(jnp.float32) + delta).astype(p.dtype)
+            if flag and use_zero and not bucketed:
+                # Bucketed mode defers the gather: shards from all leaves are
+                # re-bucketed and allgathered pipelined below.
+                with jax.named_scope("zero1.allgather"):
+                    new_p = allgather_leaf(new_loc, p.shape[0], axis_names,
+                                           sync)
+            else:
+                new_p = new_loc
+            return new_p, m2, v2
 
-        pairs = jax.tree.map(reduce_one_ef, grads, flags, opt.ef)
-        ispair = lambda x: (isinstance(x, tuple) and len(x) == 2
-                            and not isinstance(x, jax.Array))
-        g_red = jax.tree.map(lambda o: o[0], pairs, is_leaf=ispair)
-        new_ef = jax.tree.map(lambda o: o[1], pairs, is_leaf=ispair)
-    else:
-        g_red = jax.tree.map(reduce_one, grads, flags)
-        new_ef = opt.ef
-
-    if constrain is not None:
-        g_red = constrain(g_red)
-    # --- global grad norm: shards partition the reduced grad exactly, so
-    # one psum of the summed shard sq-norms + the (replicated) tiny-leaf
-    # sq-norms gives the global norm ---
-    flat_flags = jax.tree.leaves(flags)
-    flat_g = jax.tree.leaves(g_red)
-    shard_sq = sum((jnp.sum(jnp.square(g)) for g, f in
-                    zip(flat_g, flat_flags) if f and use_zero),
-                   start=jnp.zeros((), jnp.float32))
-    tiny_sq = sum((jnp.sum(jnp.square(g)) for g, f in
-                   zip(flat_g, flat_flags) if not (f and use_zero)),
-                  start=jnp.zeros((), jnp.float32))
-    for ax in axis_names:
-        shard_sq = lax.psum(shard_sq, ax)
-    gnorm = jnp.sqrt(shard_sq + tiny_sq)
-    scale = adamw.clip_scale_from_norm(opt_cfg, gnorm)
-
-    # --- AdamW on shards ---
-    step = opt.step + 1
-    t = step.astype(jnp.float32)
-    lr = adamw.lr_at(opt_cfg, step)
-    bc1 = 1 - opt_cfg.beta1 ** t
-    bc2 = 1 - opt_cfg.beta2 ** t
-
-    def update_one(p, g, m, v, flag):
-        if flag and use_zero:
-            ld = p.shape[0]
-            p_pad = _pad_lead(p, world)
-            off, rows = shard_offset(p_pad.shape[0], axis_names)
-            p_loc = lax.dynamic_slice_in_dim(p_pad, off, rows, axis=0)
-        else:
-            p_loc = p
-        g = g * scale
-        m2 = opt_cfg.beta1 * m + (1 - opt_cfg.beta1) * g
-        v2 = opt_cfg.beta2 * v + (1 - opt_cfg.beta2) * g * g
-        delta = -lr * ((m2 / bc1) / (jnp.sqrt(v2 / bc2) + opt_cfg.eps)
-                       + opt_cfg.weight_decay * p_loc.astype(jnp.float32))
-        new_loc = (p_loc.astype(jnp.float32) + delta).astype(p.dtype)
-        if flag and use_zero and not bucketed:
-            # Bucketed mode defers the gather: shards from all leaves are
-            # re-bucketed and allgathered pipelined below.
-            new_p = allgather_leaf(new_loc, p.shape[0], axis_names, sync)
-        else:
-            new_p = new_loc
-        return new_p, m2, v2
-
-    out = jax.tree.map(update_one, params, g_red, opt.m, opt.v, flags)
-    istup = lambda x: isinstance(x, tuple) and len(x) == 3 \
-        and not isinstance(x, jax.Array)
-    new_params = jax.tree.map(lambda o: o[0], out, is_leaf=istup)
-    new_m = jax.tree.map(lambda o: o[1], out, is_leaf=istup)
-    new_v = jax.tree.map(lambda o: o[2], out, is_leaf=istup)
+        out = jax.tree.map(update_one, params, g_red, opt.m, opt.v, flags)
+        istup = lambda x: isinstance(x, tuple) and len(x) == 3 \
+            and not isinstance(x, jax.Array)
+        new_params = jax.tree.map(lambda o: o[0], out, is_leaf=istup)
+        new_m = jax.tree.map(lambda o: o[1], out, is_leaf=istup)
+        new_v = jax.tree.map(lambda o: o[2], out, is_leaf=istup)
     if bucketed:
-        new_params = _bucketed_allgather(new_params, params, flags,
-                                         axis_names, sync, world)
+        with jax.named_scope("zero1.allgather"):
+            new_params = _bucketed_allgather(new_params, params, flags,
+                                             axis_names, sync, world)
 
     mloss = loss
     for ax in axis_names:
